@@ -163,8 +163,6 @@ def _group_size(line: str) -> int:
 def compiled_metrics(compiled: Any) -> Dict[str, float]:
     """Extract flops / bytes / collective bytes from a compiled executable."""
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     stats = collective_stats(compiled.as_text())
     coll_wire = sum(rec["wire_bytes"] for rec in stats.values())
     coll_count = sum(rec["count"] for rec in stats.values())

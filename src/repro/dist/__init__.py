@@ -16,7 +16,6 @@ importing the package does not initialize jax device state — required by
 the dry-run contract, which must set XLA_FLAGS first.
 """
 
-from repro import _compat  # noqa: F401  (installs jax API shims)
 from repro.core.topology import ProcessTopology  # noqa: F401
 
 __all__ = ["ProcessTopology"]

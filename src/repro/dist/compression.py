@@ -15,8 +15,6 @@ from __future__ import annotations
 
 from typing import Any, Tuple
 
-from repro import _compat  # noqa: F401
-
 import jax
 import jax.numpy as jnp
 

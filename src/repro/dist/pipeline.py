@@ -14,8 +14,6 @@ from __future__ import annotations
 
 from typing import Callable
 
-from repro import _compat  # noqa: F401
-
 import jax
 import jax.numpy as jnp
 
@@ -50,7 +48,10 @@ def pipeline_forward(
         y = stage_fn(stage_params, x)
         return jax.lax.ppermute(y, axis_name, ring), y
 
-    _, ys = jax.lax.scan(tick, jnp.zeros_like(microbatches[0]), jnp.arange(n_ticks))
+    # The carry holds what ppermute returns, which varies over the stage
+    # axis; the initial value must be typed the same way.
+    init = jax.lax.pcast(jnp.zeros_like(microbatches[0]), axis_name, to="varying")
+    _, ys = jax.lax.scan(tick, init, jnp.arange(n_ticks))
 
     # Final stage finishes microbatch i at tick i + (S-1); mask + psum
     # replicates the result across the stage axis.

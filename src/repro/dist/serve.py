@@ -15,8 +15,6 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from repro import _compat  # noqa: F401
-
 import jax
 import jax.numpy as jnp
 

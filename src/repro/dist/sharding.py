@@ -18,8 +18,6 @@ from __future__ import annotations
 
 from typing import Any, Optional, Tuple
 
-from repro import _compat  # noqa: F401
-
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -16,8 +16,6 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, Optional, Tuple
 
-from repro import _compat  # noqa: F401
-
 import jax
 import jax.numpy as jnp
 

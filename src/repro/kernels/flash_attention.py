@@ -108,7 +108,7 @@ def flash_attention_bhsd(
     window: Optional[int] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     bh, s, d = q.shape
     bk_heads, t, _ = k.shape

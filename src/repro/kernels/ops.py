@@ -1,9 +1,10 @@
 """jit'd wrappers around the Pallas kernels with backend dispatch.
 
-On CPU (this container) kernels run with ``interpret=True`` — the kernel
-body executes in Python for correctness validation; on a real TPU backend
-``interpret=False`` compiles to Mosaic.  The model layer calls these through
-config flags (``use_flash_kernel`` / ``use_scan_kernels``).
+On the CPU backend kernels run with ``interpret=True`` — the kernel body
+executes in Python for correctness validation; on a TPU backend
+``interpret=False`` compiles to Mosaic; any other backend is an error.  The
+model layer calls these through config flags (``use_flash_kernel`` /
+``use_scan_kernels``).
 """
 
 from __future__ import annotations
@@ -21,7 +22,10 @@ from .ssd import ssd_chunk_scan_blocked
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise NotImplementedError(f"Pallas kernels target TPU (interpreted on CPU), not {backend!r}")
+    return backend == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))

@@ -27,15 +27,12 @@ def _kernel(a_ref, b_ref, o_ref, h_scratch, *, block_t: int):
     def _init():
         h_scratch[...] = jnp.zeros_like(h_scratch)
 
-    h = h_scratch[0]  # (block_n,)
-    a = a_ref[0]  # (block_t, block_n)
-    b = b_ref[0]
-    out = jnp.zeros_like(b)
+    h = h_scratch[...]  # (1, block_n)
     for t in range(block_t):  # unrolled: block_t is a compile-time constant
-        h = a[t] * h + b[t]
-        out = out.at[t].set(h)
-    o_ref[0] = out
-    h_scratch[0] = h
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        o_ref[0, row, :] = h  # each row goes straight to the output block
+    h_scratch[...] = h
 
 
 def rg_lru_scan_blocked(
@@ -44,7 +41,7 @@ def rg_lru_scan_blocked(
     *,
     block_t: int = 16,
     block_n: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     bsz, s, n = a.shape
     block_t = min(block_t, s)

@@ -5,8 +5,6 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-from repro import _compat  # noqa: F401  (jax API shims: axis_types, shard_map)
-
 import jax
 import numpy as np
 
@@ -52,12 +50,13 @@ def describe(mesh) -> str:
 
 
 def elastic_setup(cfg, topology, use_mesh: bool):
-    """Common driver bootstrap: resolve the elastic mesh (when requested and
-    >1 device is visible), install activation sharding on the config, and
-    bind the mesh shape into the topology.
+    """Common driver bootstrap: resolve the elastic mesh (when requested),
+    install activation sharding on the config, and bind the mesh shape into
+    the topology.  A mesh requested with one visible device is an error,
+    not a silent unsharded run.
 
     Returns ``(cfg, mesh, mesh_ctx, topology)`` where ``mesh`` is None on
-    the single-device path and ``mesh_ctx()`` yields the context the jitted
+    the unsharded path and ``mesh_ctx()`` yields the context the jitted
     step must be *called* under — activation PartitionSpec constraints
     resolve against the ambient mesh at trace time, not jit-creation time.
     """
@@ -65,7 +64,9 @@ def elastic_setup(cfg, topology, use_mesh: bool):
 
     from repro.dist.train import with_act_sharding
 
-    if use_mesh and len(jax.devices()) > 1:
+    if use_mesh:
+        if len(jax.devices()) < 2:
+            raise ValueError("--mesh needs more than one visible device; found 1")
         mesh = make_elastic_mesh()
         return with_act_sharding(cfg, mesh), mesh, (lambda: mesh), topology.with_mesh(mesh)
     return cfg, None, contextlib.nullcontext, topology

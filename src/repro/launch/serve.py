@@ -18,6 +18,7 @@ import repro.core as rmon
 from repro.core.memsys import rss_bytes
 from repro.configs import get_config, get_smoke_config
 from repro.dist import serve as dserve
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm_init
 
 
@@ -122,6 +123,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     ns = build_parser().parse_args(argv)
+    enable_compile_cache()
     owns_measurement = False
     if ns.report or ns.agent:
         m = rmon.active()
@@ -158,7 +160,7 @@ def main(argv=None) -> int:
         run_dir = rmon.finalize()
         if run_dir and ns.report:
             print(f"report: {run_dir}/report.html")
-    return 0 if (result is None or result["finite"]) else 1
+    return 0 if (result is not None and result["finite"]) else 1
 
 
 if __name__ == "__main__":

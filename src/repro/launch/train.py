@@ -32,7 +32,8 @@ from repro.configs import get_config, get_smoke_config
 from repro.data import DataConfig, Prefetcher, SyntheticLM
 from repro.dist import sharding as shd
 from repro.dist.straggler import StragglerWatchdog
-from repro.dist.train import make_train_step
+from repro.dist.train import abstract_state, make_train_step
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm_init
 from repro.models.lm import padded_vocab
 from repro.optim import adamw
@@ -73,22 +74,23 @@ def train(
         print(f"topology: {topology.tag()} mesh={topology.mesh_shape or '(none)'}")
 
     with rmon.region("init", module="train"):
-        params = lm_init(jax.random.PRNGKey(seed), cfg)
-        opt_state = adamw.init(params)
+        key = jax.random.PRNGKey(seed)
+        # Built in place under out_shardings: made eagerly and then
+        # device_put, the whole fp32 state would first sit on device 0.
+        p_shard = o_shard = None
         if mesh is not None:
-            p_shard = shd.params_shardings(mesh, params)
-            o_shard = shd.opt_state_shardings(mesh, opt_state)
-            params = jax.device_put(params, p_shard)
-            opt_state = jax.device_put(opt_state, o_shard)
+            params_shapes, opt_shapes = abstract_state(cfg)
+            p_shard = shd.params_shardings(mesh, params_shapes)
+            o_shard = shd.opt_state_shardings(mesh, opt_shapes)
+        params = jax.jit(lambda k: lm_init(k, cfg), out_shardings=p_shard)(key)
+        opt_state = jax.jit(adamw.init, out_shardings=o_shard)(params)
 
     start_step = 0
     manager = None
     if ckpt_dir:
         manager = CheckpointManager(ckpt_dir)
         state = {"params": params, "opt": opt_state}
-        shardings = None
-        if mesh is not None:
-            shardings = {"params": p_shard, "opt": o_shard}
+        shardings = None if mesh is None else {"params": p_shard, "opt": o_shard}
         restored = manager.restore_latest(state, shardings)
         if restored is not None:
             start_step, state, extras = restored
@@ -107,6 +109,7 @@ def train(
     )
 
     losses = []
+    step_s = []
     t_train0 = time.perf_counter()
     try:
         for i in range(start_step, steps):
@@ -121,6 +124,7 @@ def train(
                 params, opt_state, stats = step_fn(params, opt_state, batch)
                 stats = jax.block_until_ready(stats)
             dt = time.perf_counter() - t0
+            step_s.append(dt)
             watchdog.observe(step_i, dt)
             loss = float(stats["loss"])
             losses.append(loss)
@@ -168,6 +172,8 @@ def train(
         "start_step": start_step,
         "final_loss": losses[-1] if losses else None,
         "first_loss": losses[0] if losses else None,
+        "losses": losses,
+        "step_s": step_s,
         "wall_s": wall,
         "straggler": watchdog.summary(),
         "topology": topology.as_dict(),
@@ -203,6 +209,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     ns = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     owns_measurement = False
     if ns.report:

@@ -200,6 +200,36 @@ def test_small_mesh_e2e_train_step_matches_single_device():
     assert "MESH_EQUIV_OK" in out
 
 
+def test_launch_train_mesh_matches_one_device():
+    """The ``--mesh`` driver path, with its state built on the mesh, gives
+    the losses of the unsharded path."""
+    out = _run_with_devices(
+        """
+        import numpy as np
+        from repro.configs import get_smoke_config
+        from repro.launch import train as lt
+        cfg = get_smoke_config("mamba2-370m")
+        kw = dict(steps=3, global_batch=4, seq_len=32, seed=0, log_every=100)
+        sharded = lt.train(cfg, use_mesh=True, **kw)
+        assert sharded["topology"]["mesh_shape"] == [1, 4], sharded["topology"]
+        single = lt.train(cfg, use_mesh=False, **kw)
+        np.testing.assert_allclose(sharded["losses"], single["losses"], rtol=1e-3)
+        print("LAUNCH_MESH_OK", sharded["losses"], single["losses"])
+        """,
+        n_devices=4,
+    )
+    assert "LAUNCH_MESH_OK" in out
+
+
+def test_launch_mesh_needs_more_than_one_device():
+    from repro.configs import get_smoke_config
+    from repro.core.topology import ProcessTopology
+    from repro.launch.mesh import elastic_setup
+
+    with pytest.raises(ValueError, match="more than one visible device"):
+        elastic_setup(get_smoke_config("mamba2-370m"), ProcessTopology(), use_mesh=True)
+
+
 # -- straggler watchdog ---------------------------------------------------------
 
 def test_straggler_watchdog_flags_and_mitigates():

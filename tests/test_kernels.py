@@ -86,7 +86,13 @@ def test_flash_attention_property(s, h, g, d, causal):
 
 @pytest.mark.parametrize(
     "b,s,n,block_t,block_n",
-    [(1, 64, 128, 16, 128), (2, 128, 256, 16, 128), (1, 48, 128, 8, 64), (3, 32, 384, 32, 128)],
+    [
+        (1, 64, 128, 16, 128),
+        (2, 128, 256, 16, 128),
+        (1, 48, 128, 8, 64),
+        (3, 32, 384, 32, 128),
+        (2, 64, 640, 16, 128),  # the layout the chip compiles: (16, 128) blocks
+    ],
 )
 def test_rg_lru_vs_ref(b, s, n, block_t, block_n):
     k1, k2 = jax.random.split(jax.random.PRNGKey(2))
@@ -140,6 +146,7 @@ def test_rg_lru_property_bounded(s, n, decay):
         (2, 128, 4, 64, 1, 32, 32),
         (1, 64, 4, 32, 2, 16, 16),  # grouped B/C
         (1, 256, 2, 64, 1, 128, 64),  # larger state
+        (2, 128, 4, 64, 1, 128, 64),  # mamba2-370m head layout, chip tiling
     ],
 )
 def test_ssd_kernel_vs_sequential_ref(b, s, h, p, g, n, chunk):

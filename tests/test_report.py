@@ -221,6 +221,7 @@ def test_launch_train_report_flag(tmp_path, monkeypatch):
 
     monkeypatch.setattr(lt, "train", lambda cfg, **kw: {"final_loss": 1.0})
     monkeypatch.setattr(lt, "get_smoke_config", lambda arch: object())
+    monkeypatch.setattr(lt, "enable_compile_cache", lambda: None)
     monkeypatch.chdir(tmp_path)
     assert lt.main(["--arch", "stub", "--smoke", "--report"]) == 0
     runs = list((tmp_path / "repro-traces").glob("train-*"))
@@ -237,6 +238,7 @@ def test_launch_train_report_flag_under_scorep(tmp_path, monkeypatch):
 
     monkeypatch.setattr(lt, "train", lambda cfg, **kw: {"final_loss": 1.0})
     monkeypatch.setattr(lt, "get_smoke_config", lambda arch: object())
+    monkeypatch.setattr(lt, "enable_compile_cache", lambda: None)
     d = str(tmp_path / "outer")
     rmon.init(instrumenter="profile", substrates=("profiling",), run_dir=d,
               experiment="outer")

@@ -1,0 +1,92 @@
+"""Compile the Pallas kernels for a described TPU v5e, at real widths.
+
+Nothing runs: the TPU compiler, which is installed with jax, lowers each
+kernel for a ``v5e:2x2`` topology that is described, not attached, and
+refuses what the chip's compiler would refuse (unaligned blocks, primitives
+Mosaic cannot lower, too much VMEM).  Each compile must contain the Mosaic
+kernel (``tpu_custom_call``), so a silent fallback to XLA cannot pass.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library at a time, and every test worker
+imports this file.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.rg_lru import rg_lru_scan_blocked
+from repro.kernels.ssd import ssd_chunk_scan_blocked
+
+SEQ = 4096
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler, or its library is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile_text(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize(
+    "heads,kv_heads,head_dim,window",
+    [
+        (32, 8, 128, None),  # mistral-nemo-12b: GQA 4:1, causal
+        (16, 8, 256, 1024),  # gemma3-12b local layers: sliding window
+    ],
+    ids=["gqa", "windowed"],
+)
+def test_flash_attention_compiles_for_v5e(one_chip, heads, kv_heads, head_dim, window):
+    fn = functools.partial(
+        flash_attention_bhsd, n_q_per_kv=heads // kv_heads, scale=head_dim**-0.5,
+        causal=True, window=window, interpret=False)
+    _compile_text(
+        fn, one_chip,
+        ((heads, SEQ, head_dim), jnp.bfloat16),
+        ((kv_heads, SEQ, head_dim), jnp.bfloat16),
+        ((kv_heads, SEQ, head_dim), jnp.bfloat16),
+    )
+
+
+def test_rg_lru_compiles_for_v5e(one_chip):
+    # recurrentgemma-2b: lru_width 2560
+    fn = functools.partial(rg_lru_scan_blocked, block_t=16, block_n=128, interpret=False)
+    _compile_text(fn, one_chip, ((1, SEQ, 2560), jnp.float32), ((1, SEQ, 2560), jnp.float32))
+
+
+def test_ssd_compiles_for_v5e(one_chip):
+    # mamba2-370m: 32 heads x 64, d_state 128, one B/C group, chunk 64
+    fn = functools.partial(ssd_chunk_scan_blocked, chunk=64, interpret=False)
+    _compile_text(
+        fn, one_chip,
+        ((1, SEQ, 32, 64), jnp.float32),
+        ((1, SEQ, 32), jnp.float32),
+        ((32,), jnp.float32),
+        ((1, SEQ, 1, 128), jnp.float32),
+        ((1, SEQ, 1, 128), jnp.float32),
+    )
