@@ -1,66 +1,107 @@
-"""JAX integration — step regions, device metrics, collective accounting.
+"""JAX integration: model-layer names on device work, the monitor's host
+spans on the profiler's clock, and AOT cost numbers.
 
 The paper instruments MPI/pthread/CUDA activity alongside Python regions.
-The XLA analogue: device work is compiled, so there is no per-kernel host
-callback — instead we (a) tag host-side dispatch with user regions +
-``jax.named_scope`` (region names survive into HLO metadata, the moral
-equivalent of Score-P's region handles crossing the language boundary),
-and (b) attach AOT cost-model numbers (FLOPs, bytes, per-collective bytes)
-as metrics on the step region, giving profiles the device dimension the
-paper gets from CUPTI.
+Device work under XLA is compiled, so there is no per-kernel host callback;
+three things stand in for one:
+
+- ``scope(name)`` names a model layer with ``jax.named_scope``. The name
+  survives ``jax.checkpoint``, ``lax.scan`` and transposition into the
+  optimized HLO's ``op_name`` metadata, so a profiler trace's device
+  operations can be charged to the layer (recomputation carries
+  ``rematted_computation`` in the same path). ``LAYER_SCOPES`` is the table
+  of names.
+- ``JaxBridge`` is what a measurement adds once ``jax`` is imported: while a
+  profiler session is on, user regions and GC pauses are written into the
+  profiler's own trace as ``jax.profiler.TraceAnnotation`` spans, on the
+  device trace's clock; and JAX's compile events become ``jax.compile.*``
+  metrics on the monitor's clock.
+- ``compiled_metrics`` / ``collective_stats`` read AOT cost numbers.
 """
 
 from __future__ import annotations
 
 import re
-import time
-from contextlib import contextmanager
-from functools import wraps
-from typing import Any, Callable, Dict, Optional
-
-from . import measurement as _m
+from typing import Any, Dict
 
 try:  # jax is an optional dependency of the core (monitoring works without it)
     import jax
+    import jax.monitoring
+    from jax.profiler import TraceAnnotation
 except Exception:  # pragma: no cover
     jax = None
+    TraceAnnotation = None
+
+#: The model layers that device time is charged to, by ``jax.named_scope``
+#: name, and where each is applied on the train path.
+LAYER_SCOPES: Dict[str, str] = {
+    "embed": "models/lm.py _embed_tokens: token embedding lookup",
+    "norm": "models/transformer.py norm_apply: RMS and layer norms",
+    "layer_stack": "models/transformer.py stack_apply: the group scan's own slicing, stacking and carries",
+    "ssd_proj": "models/ssd.py ssd_apply outside the chunk scan: in_proj split, conv, gate, norm, out_proj",
+    "ssd_scan": "models/ssd.py ssd_apply: the chunked SSD scan, jnp or Pallas",
+    "attn_proj": "models/attention.py gqa_apply: qkv projection, rope, output projection",
+    "attn_core": "models/attention.py gqa_apply: scores, softmax and PV, chunked, naive or flash",
+    "mlp": "models/transformer.py _ffn_apply: the dense feed-forward",
+    "head_loss": "models/lm.py lm_loss: logits and cross entropy",
+    "param_cast": "dist/train.py _cast_params_for_compute: mixed-precision weight cast",
+    "optimizer": "optim/adamw.py update: clip, moments, step",
+}
+
+#: JAX compile events (``jax.monitoring`` durations) and the monitor metric
+#: each becomes. ``backend_s`` wraps ``compile_or_get_cached``, so it holds a
+#: persistent-cache hit's load, which ``cache_load_s`` reports on its own.
+COMPILE_EVENTS: Dict[str, str] = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.compile.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.compile.lower_s",
+    "/jax/core/compile/backend_compile_duration": "jax.compile.backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.compile.cache_load_s",
+}
+
+#: Prefix of the monitor's spans in a profiler trace (GC pauses: ``repro/gc``,
+#: memsys.poller).
+SPAN_PREFIX = "repro/"
 
 
-@contextmanager
-def annotate(name: str):
-    """Host region + XLA named scope in one context manager."""
-    if jax is None:
-        with _m.region(name, module="jax"):
-            yield
-        return
-    with _m.region(name, module="jax"), jax.named_scope(name):
-        yield
+def scope(name: str):
+    """``jax.named_scope(name)`` for a model layer named in ``LAYER_SCOPES``."""
+    if name not in LAYER_SCOPES:
+        raise ValueError(f"{name!r} is not a layer scope; LAYER_SCOPES has {sorted(LAYER_SCOPES)}")
+    return jax.named_scope(name)
 
 
-def instrument_step(fn: Callable, name: str, *, block: bool = True) -> Callable:
-    """Wrap a (possibly jitted) step function with host-side step regions.
+class JaxBridge:
+    """One measurement's hooks into a loaded ``jax``.
 
-    Records ``<name>`` as a region per call and a ``<name>.ms`` metric.  With
-    ``block=True`` the wrapper calls ``block_until_ready`` on the result so
-    the region covers device execution, not just dispatch (async dispatch
-    would otherwise make steps look free — the JAX-flavored pitfall of the
-    paper's host-side methodology).
+    ``span`` is the profiler's annotation class; callers gate every span on
+    ``span.is_enabled()``, so with no profiler session on nothing is built.
+    Created by the measurement once ``jax`` is imported; ``close`` removes
+    the compile-event listener.
     """
 
-    @wraps(fn)
-    def wrapper(*args, **kwargs):
-        m = _m.active()
-        if m is None:
-            return fn(*args, **kwargs)
-        t0 = time.perf_counter_ns()
-        with m.region(name, module="jax.step"):
-            out = fn(*args, **kwargs)
-            if block and jax is not None:
-                out = jax.block_until_ready(out)
-        m.metric(f"{name}.ms", (time.perf_counter_ns() - t0) / 1e6)
-        return out
+    def __init__(self, measurement):
+        self.span = TraceAnnotation
+        self._m = measurement
+        self._names: Dict[int, str] = {}
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
 
-    return wrapper
+    def _on_duration(self, event: str, duration: float, **_: Any) -> None:
+        name = COMPILE_EVENTS.get(event)
+        if name is not None:
+            self._m.metric(name, duration)
+
+    def region_span(self, rid: int):
+        """Open and return the span ``repro/<module>/<name>`` of user region ``rid``."""
+        name = self._names.get(rid)
+        if name is None:
+            region = self._m.regions.get(rid)
+            name = self._names[rid] = f"{SPAN_PREFIX}{region.module}/{region.name}"
+        span = self.span(name)
+        span.__enter__()
+        return span
+
+    def close(self) -> None:
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
 
 
 # ----------------------------------------------------------------------------
@@ -179,12 +220,3 @@ def compiled_metrics(compiled: Any) -> Dict[str, float]:
             out[attr] = float(getattr(mem, attr, 0) or 0)
     return out
 
-
-def record_compiled(name: str, compiled: Any) -> Dict[str, float]:
-    """Attach compiled-artifact metrics to the active measurement."""
-    metrics = compiled_metrics(compiled)
-    m = _m.active()
-    if m is not None:
-        for key, value in metrics.items():
-            m.metric(f"{name}.{key}", value)
-    return metrics

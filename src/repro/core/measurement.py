@@ -21,6 +21,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 import warnings
@@ -31,6 +32,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from .buffer import BUFFER_STRATEGIES, EV_ENTER, EV_EXIT
 from .filtering import Filter
 from .instrumenters import make_instrumenter
+from .memsys.poller import GcWatcher
 from .memsys.substrate import DEFAULT_PERIOD_S, DEFAULT_TOPN
 from .regions import RegionRegistry
 from .schema import stamp
@@ -205,10 +207,20 @@ class Measurement:
 
     Thread-safe event intake: each thread appends to its own buffer; flushes
     fan batches out to the substrates under one lock.
+
+    The measurement owns the one GC watcher (the memory substrate reads its
+    pauses) and, once ``jax`` is imported, a ``jax_events.JaxBridge``: while
+    a profiler session is on, user regions and GC pauses are mirrored into
+    the profiler's trace as ``repro/...`` spans, and JAX compile events are
+    recorded as ``jax.compile.*`` metrics.
     """
 
     def __init__(self, config: MeasurementConfig):
         self.config = config
+        self.gc = GcWatcher()
+        #: repro.core.jax_events.JaxBridge, made at start or on the first
+        #: region or flush after ``jax`` is imported; None until then.
+        self.jax = None
         self.filter = Filter.from_spec(config.filter_spec)
         self.regions = RegionRegistry(decide=self.filter.decide)
         self._local = threading.local()
@@ -223,9 +235,8 @@ class Measurement:
             elif name == "metrics":
                 self._substrates.append(make_substrate(name, keep_series=config.keep_series))
             elif name == "memory":
-                self._substrates.append(
-                    make_substrate(name, period=config.memory_period, topn=config.memory_topn)
-                )
+                self._substrates.append(make_substrate(
+                    name, period=config.memory_period, topn=config.memory_topn, gc=self.gc))
             else:
                 self._substrates.append(make_substrate(name))
         if config.instrumenter == "sampling":
@@ -289,6 +300,8 @@ class Measurement:
         return buf
 
     def _on_flush(self, thread_id: int, columns) -> None:
+        if self.jax is None and _jax_imported():
+            self._bridge_jax()
         with self._flush_lock:
             for sub in self._substrates:
                 sub.on_flush(thread_id, columns)
@@ -332,6 +345,9 @@ class Measurement:
             with open(os.path.join(self.run_dir, _PLAN_ARTIFACT), "w") as fh:
                 json.dump(self.static_plan, fh, indent=1)
         self.started = True
+        self.gc.install()
+        if _jax_imported():
+            self._bridge_jax()
         if self.config.agent:
             self.attach_agent()
         if self.governor is not None:
@@ -342,6 +358,16 @@ class Measurement:
         self.instrumenter.install(self)
         if self.governor is not None:
             self.governor.open()
+
+    def _bridge_jax(self) -> None:
+        """Make the JAX bridge once ``jax`` is imported, for a live run."""
+        with self._buffers_lock:
+            if self.jax is not None or not self.started or self.finalized:
+                return
+            from .jax_events import JaxBridge  # late: imports jax
+
+            self.jax = JaxBridge(self)
+            self.gc.span = self.jax.span
 
     def attach_agent(self, port: Optional[int] = None):
         """Turn on the live-monitoring agent for a started measurement.
@@ -403,6 +429,10 @@ class Measurement:
             self.governor.frozen = True
             self.governor.stop_watchdog()
         self.instrumenter.uninstall()
+        self.gc.uninstall()
+        self.gc.span = None
+        if self.jax is not None:
+            self._best_effort("jax bridge close", self.jax.close)
         with self._buffers_lock:
             buffers = list(self._buffers)
         for buf in buffers:
@@ -488,6 +518,8 @@ class Measurement:
     # -- user instrumentation API ---------------------------------------------
 
     def region(self, name: str, module: str = "user"):
+        if self.jax is None and _jax_imported():
+            self._bridge_jax()
         rid = self.regions.register_user(name, module)
         return _RegionContext(self, rid)
 
@@ -505,18 +537,33 @@ class Measurement:
         return None
 
 
-class _RegionContext:
-    """Reusable enter/exit context for one user region (cheap hot path)."""
+def _jax_imported() -> bool:
+    """``jax`` is imported and done importing (a flush under the ``profile``
+    instrumenter can run in the middle of ``import jax``)."""
+    mod = sys.modules.get("jax")
+    return mod is not None and not getattr(getattr(mod, "__spec__", None), "_initializing", False)
 
-    __slots__ = ("_m", "_rid")
+
+class _RegionContext:
+    """Reusable enter/exit context for one user region (cheap hot path).
+
+    While a profiler session is on, the region is also a ``repro/<module>/
+    <name>`` span in the profiler's trace; with none on, no span is built."""
+
+    __slots__ = ("_m", "_rid", "_span")
 
     def __init__(self, measurement: Measurement, rid: int):
         self._m = measurement
         self._rid = rid
+        self._span = None
 
     def __enter__(self):
         if self._rid >= 0:
-            buf = self._m.thread_buffer()
+            m = self._m
+            bridge = m.jax
+            if bridge is not None and bridge.span.is_enabled():
+                self._span = bridge.region_span(self._rid)
+            buf = m.thread_buffer()
             buf.events.append((EV_ENTER, self._rid, time.perf_counter_ns(), 0))
         return self
 
@@ -526,6 +573,9 @@ class _RegionContext:
             buf.events.append((EV_EXIT, self._rid, time.perf_counter_ns(), 0))
             if len(buf.events) >= buf.flush_threshold:
                 buf.flush()
+            if self._span is not None:
+                self._span.__exit__(None, None, None)
+                self._span = None
         return False
 
 

@@ -12,7 +12,9 @@ week-long run costs the same memory as a minute-long one.
 
 GC pauses come from ``gc.callbacks`` — the interpreter invokes the
 callback synchronously around each collection, so the delta between the
-"start" and "stop" phases is the actual stop-the-world pause.
+"start" and "stop" phases is the actual stop-the-world pause.  The
+measurement owns one watcher; while a profiler session is on it also writes
+each pause into the profiler's trace as a ``repro/gc`` span.
 """
 
 from __future__ import annotations
@@ -24,6 +26,8 @@ import tracemalloc
 from typing import Dict, List, Optional
 
 from .sysinfo import open_fd_count, rss_bytes, rss_source
+
+GC_SPAN = "repro/gc"
 
 
 class SystemPoller:
@@ -90,7 +94,11 @@ class SystemPoller:
 
 
 class GcWatcher:
-    """Accumulates GC pause time / counts via ``gc.callbacks``."""
+    """Accumulates GC pause time / counts via ``gc.callbacks``.
+
+    ``span``: the profiler's annotation class (``jax.profiler.
+    TraceAnnotation``) once the measurement has one, else None; a pause is
+    a ``repro/gc`` span when ``span.is_enabled()``."""
 
     def __init__(self, max_samples: int = 1 << 12):
         self.max_samples = max(int(max_samples), 16)
@@ -102,12 +110,21 @@ class GcWatcher:
         self.per_generation: Dict[int, Dict[str, int]] = {}
         self._t0 = 0
         self._installed = False
+        self.span = None
+        self._open_span = None
 
     def _callback(self, phase: str, info: Dict[str, int]) -> None:
         if phase == "start":
+            span = self.span
+            if span is not None and span.is_enabled():
+                self._open_span = span(GC_SPAN)
+                self._open_span.__enter__()
             self._t0 = time.perf_counter_ns()
             return
         now = time.perf_counter_ns()
+        if self._open_span is not None:
+            self._open_span.__exit__(None, None, None)
+            self._open_span = None
         pause = now - self._t0 if self._t0 else 0
         self._t0 = 0
         self.collections += 1

@@ -1,8 +1,8 @@
 """The ``memory`` measurement substrate — memory.json writer.
 
 Composes the heap collector (per-region allocation attribution), the
-system poller (RSS / heap / fd timelines), and the GC watcher into one
-substrate.  Artifact:
+system poller (RSS / heap / fd timelines), and the measurement's GC watcher
+into one substrate.  Artifact:
 
     memory.json
       heap      per-region alloc/net bytes + blocks, per-thread peaks
@@ -15,9 +15,9 @@ substrate.  Artifact:
                 Perfetto counter tracks next to the metrics.json series.
 
 Disabled by default; enabled via ``REPRO_MONITOR_MEMORY=1`` or by listing
-``memory`` in the substrates.  When disabled no collector, poller, or GC
-callback is installed and tracemalloc stays off, so the event fast path
-and the flush path are untouched.
+``memory`` in the substrates.  When disabled no collector or poller is
+installed and tracemalloc stays off, so the event fast path and the flush
+path are untouched (the measurement's GC watcher is on either way).
 """
 
 from __future__ import annotations
@@ -42,15 +42,18 @@ class MemorySubstrate(Substrate):
 
     def __init__(
         self,
+        gc: GcWatcher,
         period: float = DEFAULT_PERIOD_S,
         topn: int = DEFAULT_TOPN,
         trace_python: bool = True,
     ):
+        """``gc``: the measurement's watcher, installed and removed by the
+        measurement; this substrate only reads it."""
         self.period = float(period)
         self.topn = int(topn)
         self.heap = HeapCollector(trace_python=trace_python)
         self.poller = SystemPoller(period_s=self.period)
-        self.gc = GcWatcher()
+        self.gc = gc
         self._run_dir = ""
         self._meta: Dict[str, Any] = {}
 
@@ -58,7 +61,6 @@ class MemorySubstrate(Substrate):
         self._run_dir = run_dir
         self._meta = meta
         self.heap.open()
-        self.gc.install()
         self.poller.sample()  # opening endpoint even for sub-period runs
         self.poller.start()
 
@@ -67,7 +69,6 @@ class MemorySubstrate(Substrate):
 
     def close(self, region_table: List[Dict[str, Any]]) -> None:
         self.poller.stop()
-        self.gc.uninstall()
         self.heap.close()
         doc = self.document(region_table)
         with open(os.path.join(self._run_dir, ARTIFACT), "w") as fh:

@@ -1,9 +1,10 @@
 """Metrics substrate — counters and per-step series (Score-P metric plugins).
 
 Collects user metrics (``repro.core.metric(name, value)``) as time series and
-aggregates; the JAX integration layer feeds per-step wall times, HLO FLOPs /
-bytes from ``cost_analysis`` and collective-byte counters through this
-substrate.  Events themselves are summarized only by count (cheap).
+aggregates: the launch drivers' per-step times, losses and RSS, and, once
+``jax`` is imported, JAX's compile events as ``jax.compile.*`` durations
+(``repro.core.jax_events.JaxBridge``).  Events themselves are summarized
+only by count (cheap).
 
 Non-finite metric values (a NaN loss is a fact of life in training) must not
 poison the artifacts: aggregates are computed over the finite samples (with a

@@ -19,6 +19,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.jax_events import scope
 from repro.dist import sharding as shd
 from repro.models import lm_init, lm_loss
 from repro.optim import adamw
@@ -43,7 +44,8 @@ def _cast_params_for_compute(params, dtype):
             return p.astype(target)
         return p
 
-    return jax.tree.map(cast, params)
+    with scope("param_cast"):
+        return jax.tree.map(cast, params)
 
 
 def make_train_step(cfg, opt_cfg: adamw.AdamWConfig) -> Callable:

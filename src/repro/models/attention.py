@@ -18,6 +18,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.jax_events import scope
+
 from .layers import apply_rope, dense_init, rms_norm, rope_tables
 
 Params = Dict[str, Any]
@@ -187,37 +189,39 @@ def gqa_apply(
     dtype = x.dtype
     b, s, d = x.shape
     g = n_heads // n_kv_heads
-    q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
-    if positions is None:
-        positions = jnp.arange(s)
-    cos, sin = rope_tables(positions, head_dim, rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    if act_pspec is not None:
-        b_ax, s_ax = act_pspec
-        q = _constrain(q, (b_ax, s_ax, None, None))  # query: SP over seq
-        k = _constrain(k, (b_ax, None, None, None))  # K/V: gathered once
-        v = _constrain(v, (b_ax, None, None, None))
-    scale = 1.0 / math.sqrt(head_dim)
-    if use_flash_kernel:
-        from repro.kernels import ops as _kops
+    with scope("attn_proj"):
+        q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
+        if positions is None:
+            positions = jnp.arange(s)
+        cos, sin = rope_tables(positions, head_dim, rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        if act_pspec is not None:
+            b_ax, s_ax = act_pspec
+            q = _constrain(q, (b_ax, s_ax, None, None))  # query: SP over seq
+            k = _constrain(k, (b_ax, None, None, None))  # K/V: gathered once
+            v = _constrain(v, (b_ax, None, None, None))
+        scale = 1.0 / math.sqrt(head_dim)
+        with scope("attn_core"):
+            if use_flash_kernel:
+                from repro.kernels import ops as _kops
 
-        out = _kops.flash_attention(
-            q, k, v, causal=causal, window=window
-        ).reshape(b, s, n_kv_heads, g, head_dim)
-    else:
-        q = q.reshape(b, s, n_kv_heads, g, head_dim)
-        if chunk_q and s > chunk_q and s % chunk_q == 0:
-            out = _sdpa_q_chunked(q, k, v, scale, causal=causal, window=window,
-                                  chunk=chunk_q, act_pspec=act_pspec)
-        else:
-            if causal:
-                mask = causal_mask(s, s, window=window)
+                out = _kops.flash_attention(
+                    q, k, v, causal=causal, window=window
+                ).reshape(b, s, n_kv_heads, g, head_dim)
             else:
-                mask = jnp.zeros((s, s), jnp.float32)
-            out = _sdpa(q, k, v, mask, scale, act_pspec=act_pspec)
-    out = out.reshape(b, s, n_heads * head_dim)
-    return jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(dtype))
+                q = q.reshape(b, s, n_kv_heads, g, head_dim)
+                if chunk_q and s > chunk_q and s % chunk_q == 0:
+                    out = _sdpa_q_chunked(q, k, v, scale, causal=causal, window=window,
+                                          chunk=chunk_q, act_pspec=act_pspec)
+                else:
+                    if causal:
+                        mask = causal_mask(s, s, window=window)
+                    else:
+                        mask = jnp.zeros((s, s), jnp.float32)
+                    out = _sdpa(q, k, v, mask, scale, act_pspec=act_pspec)
+        out = out.reshape(b, s, n_heads * head_dim)
+        return jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(dtype))
 
 
 def cross_attention_apply(

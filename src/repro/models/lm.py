@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.jax_events import scope
 from . import attention as attn
 from . import rglru as rglru_mod
 from . import ssd as ssd_mod
@@ -84,10 +85,11 @@ def lm_init(key, cfg: ModelConfig) -> Params:
 # ----------------------------------------------------------------------------
 
 def _embed_tokens(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Array:
-    x = embed_lookup(params["embed"], tokens)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-    return x
+    with scope("embed"):
+        x = embed_lookup(params["embed"], tokens)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        return x
 
 
 def _encoder_out(cfg: ModelConfig, params: Params, frames: jax.Array) -> jax.Array:
@@ -144,16 +146,17 @@ def lm_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]) -> Tu
         frames=batch.get("frames"),
     )
     labels = batch["labels"]
-    if batch.get("patches") is not None:
-        hidden = hidden[:, -labels.shape[1] :]  # loss over text positions only
-    head = _head_matrix(cfg, params)
-    if cfg.chunked_loss_chunks > 1:
-        ce = chunked_cross_entropy(hidden, head, labels, cfg.chunked_loss_chunks, cfg.logit_softcap)
-    else:
-        logits = lm_logits(hidden, head, cfg.logit_softcap)
-        ce = jnp.mean(softmax_cross_entropy(logits, labels))
-    aux_w = cfg.moe.aux_weight if cfg.moe is not None else 0.0
-    loss = ce + aux_w * aux
+    with scope("head_loss"):
+        if batch.get("patches") is not None:
+            hidden = hidden[:, -labels.shape[1] :]  # loss over text positions only
+        head = _head_matrix(cfg, params)
+        if cfg.chunked_loss_chunks > 1:
+            ce = chunked_cross_entropy(hidden, head, labels, cfg.chunked_loss_chunks, cfg.logit_softcap)
+        else:
+            logits = lm_logits(hidden, head, cfg.logit_softcap)
+            ce = jnp.mean(softmax_cross_entropy(logits, labels))
+        aux_w = cfg.moe.aux_weight if cfg.moe is not None else 0.0
+        loss = ce + aux_w * aux
     return loss, {"ce": ce, "aux": aux}
 
 

@@ -15,6 +15,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.jax_events import scope
+
 from .layers import dense_init, rms_norm
 
 Params = Dict[str, Any]
@@ -153,27 +155,29 @@ def ssd_apply(
     """Full-sequence Mamba-2 block. x (B,S,D)."""
     dtype = x.dtype
     n_heads = d_inner // head_dim
-    z, xbc, dt = _split_proj(params, x, d_inner, n_groups, d_state, n_heads)
-    xbc, _ = _conv(xbc, params["conv_w"], params["conv_b"])
-    xin, b_in, c_in = jnp.split(xbc, [d_inner, d_inner + n_groups * d_state], axis=-1)
+    with scope("ssd_proj"):
+        z, xbc, dt = _split_proj(params, x, d_inner, n_groups, d_state, n_heads)
+        xbc, _ = _conv(xbc, params["conv_w"], params["conv_b"])
+        xin, b_in, c_in = jnp.split(xbc, [d_inner, d_inner + n_groups * d_state], axis=-1)
 
-    bsz, s, _ = x.shape
-    xh = xin.astype(jnp.float32).reshape(bsz, s, n_heads, head_dim)
-    bi = b_in.astype(jnp.float32).reshape(bsz, s, n_groups, d_state)
-    ci = c_in.astype(jnp.float32).reshape(bsz, s, n_groups, d_state)
-    dtv = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (B,S,H)
-    a = -jnp.exp(params["a_log"])  # (H,)
+        bsz, s, _ = x.shape
+        xh = xin.astype(jnp.float32).reshape(bsz, s, n_heads, head_dim)
+        bi = b_in.astype(jnp.float32).reshape(bsz, s, n_groups, d_state)
+        ci = c_in.astype(jnp.float32).reshape(bsz, s, n_groups, d_state)
+        dtv = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (B,S,H)
+        a = -jnp.exp(params["a_log"])  # (H,)
 
-    if use_kernel:
-        from repro.kernels import ops as _kops
+        with scope("ssd_scan"):
+            if use_kernel:
+                from repro.kernels import ops as _kops
 
-        y, _ = _kops.ssd_chunk_scan(xh, dtv, a, bi, ci, chunk=chunk)
-    else:
-        y, _ = ssd_chunked_ref(xh, dtv, a, bi, ci, chunk=chunk)
-    y = y + xh * params["d_skip"][None, None, :, None]
-    y = y.reshape(bsz, s, d_inner).astype(dtype)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(dtype), params["norm"])
-    return jnp.einsum("bsn,nd->bsd", y, params["out_proj"].astype(dtype))
+                y, _ = _kops.ssd_chunk_scan(xh, dtv, a, bi, ci, chunk=chunk)
+            else:
+                y, _ = ssd_chunked_ref(xh, dtv, a, bi, ci, chunk=chunk)
+        y = y + xh * params["d_skip"][None, None, :, None]
+        y = y.reshape(bsz, s, d_inner).astype(dtype)
+        y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(dtype), params["norm"])
+        return jnp.einsum("bsn,nd->bsd", y, params["out_proj"].astype(dtype))
 
 
 # -- decode -------------------------------------------------------------------

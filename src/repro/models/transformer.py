@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.jax_events import scope
 from . import attention as attn
 from . import moe as moe_mod
 from . import rglru as rglru_mod
@@ -51,9 +52,10 @@ def norm_init(cfg: ModelConfig) -> Params:
 
 
 def norm_apply(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
-    if cfg.norm_type == "layer":
-        return layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)
-    return rms_norm(x, p["scale"], cfg.norm_eps)
+    with scope("norm"):
+        if cfg.norm_type == "layer":
+            return layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)
+        return rms_norm(x, p["scale"], cfg.norm_eps)
 
 
 # ----------------------------------------------------------------------------
@@ -137,10 +139,11 @@ def _ffn_apply(cfg: ModelConfig, spec: Tuple[str, str], p: Params, x: jax.Array)
         return x, zero
     h = norm_apply(cfg, p["norm2"], x)
     if ffn == "mlp":
-        if cfg.gated_mlp:
-            out = mlp_apply(p["ffn"], h, cfg.activation)
-        else:
-            out = plain_mlp_apply(p["ffn"], h)
+        with scope("mlp"):
+            if cfg.gated_mlp:
+                out = mlp_apply(p["ffn"], h, cfg.activation)
+            else:
+                out = plain_mlp_apply(p["ffn"], h)
         return x + out, zero
     m = cfg.moe
     out, aux = moe_mod.moe_apply(p["ffn"], h, n_experts=m.n_experts, top_k=m.top_k,
@@ -279,11 +282,12 @@ def stack_apply(
             return (x, aux), None
 
         body = _maybe_remat(cfg, group_body)
-        if cfg.scan_layers:
-            (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
-        else:  # unrolled: every layer visible to the XLA cost model
-            for g in range(cfg.n_groups):
-                (x, aux), _ = body((x, aux), jax.tree.map(lambda t: t[g], params["groups"]))
+        with scope("layer_stack"):
+            if cfg.scan_layers:
+                (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
+            else:  # unrolled: every layer visible to the XLA cost model
+                for g in range(cfg.n_groups):
+                    (x, aux), _ = body((x, aux), jax.tree.map(lambda t: t[g], params["groups"]))
 
     for i, spec in enumerate(cfg.tail_pattern):
         x, a = block_apply(cfg, spec, params["tail"][i], x,

@@ -8,6 +8,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.jax_events import scope
+
 Params = Any
 OptState = Dict[str, Any]
 
@@ -44,6 +46,11 @@ def update(
     params: Params,
 ) -> Tuple[Params, OptState, Dict[str, jax.Array]]:
     """Returns (new_params, new_state, stats)."""
+    with scope("optimizer"):
+        return _update(cfg, grads, state, params)
+
+
+def _update(cfg: AdamWConfig, grads: Params, state: OptState, params: Params):
     count = state["count"] + 1
     gnorm = global_norm(grads)
     if cfg.grad_clip_norm > 0:

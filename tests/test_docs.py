@@ -114,6 +114,16 @@ def test_artifacts_md_documents_every_artifact():
     )
 
 
+def test_artifacts_md_documents_profiler_spans_and_compile_metrics():
+    from repro.core.jax_events import COMPILE_EVENTS, LAYER_SCOPES, SPAN_PREFIX
+    from repro.core.memsys.poller import GC_SPAN
+
+    doc = _read("docs", "ARTIFACTS.md")
+    for needle in [*COMPILE_EVENTS, *COMPILE_EVENTS.values(), *LAYER_SCOPES, GC_SPAN,
+                   f"{SPAN_PREFIX}<module>/<name>"]:
+        assert f"`{needle}`" in doc, f"docs/ARTIFACTS.md must document {needle!r}"
+
+
 def test_readme_advertises_executable_flows():
     readme = _read("README.md")
     # The quickstart command CI actually executes, verbatim.
