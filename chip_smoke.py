@@ -19,7 +19,9 @@ Phases on one chip:
   serve    the same config under the same measurement: batch 8, prompt
            1024, 32 generated tokens
   kernels  the Pallas kernels from ``kernels/ops.py``, compiled for the chip
-           at real widths, against the plain references in ``kernels/ref.py``
+           at real widths, against the plain references in ``kernels/ref.py``;
+           the SSD scan's gradient (its backward kernel) too, against
+           autodiff of the reference
 
 With ``--four-chips`` only the sharded path runs: the ``--mesh`` train path
 (data 1 x model 4) and, for comparison, the same config, seed and batch on
@@ -52,14 +54,19 @@ KERNEL_SEQ = 4096
 # (atol, rtol) per kernel: pass when |out - ref| <= atol + rtol * |ref|
 # everywhere.  Flash attention reads and writes bf16; the SSD kernel's
 # matmuls run at HIGHEST precision (it reads 3.2e-4 at |ref| up to 5.6 on
-# a v5e; one bf16 pass through the MXU reads 2.4e-2); the RG-LRU scan is
-# elementwise fp32.  References run at "highest" matmul precision.
+# a v5e; one bf16 pass through the MXU reads 2.4e-2), and so do its
+# gradient's; the RG-LRU scan is elementwise fp32.  References run at
+# "highest" matmul precision.
 KERNEL_TOL = {
     "flash_gqa": (2e-2, 2e-2),
     "flash_window": (2e-2, 2e-2),
     "rg_lru": (1e-4, 1e-4),
     "ssd": (1e-3, 0.0),
+    "ssd_vjp": (1e-3, 0.0),
 }
+# The SSD gradient's shapes: the reference's autodiff keeps the (P, N)
+# state of every position, 1 MiB each at one sequence of 32 heads.
+SSD_VJP_SHAPE = dict(batch=1, seq=2048)
 
 
 def log(msg: str) -> None:
@@ -182,11 +189,68 @@ def _output(result):
     return result[0] if isinstance(result, tuple) else result
 
 
+def within(name: str, out, expect, label: str = "") -> bool:
+    """Log how far ``out`` is from ``expect`` against ``name``'s tolerance;
+    True when it is within."""
+    import numpy as np
+
+    atol, rtol = KERNEL_TOL[name]
+    out, expect = np.asarray(out, np.float64), np.asarray(expect, np.float64)
+    err = np.abs(out - expect)
+    max_err = float(np.max(err))
+    excess = float(np.max(err - rtol * np.abs(expect)))
+    ok = bool(np.all(np.isfinite(out))) and excess <= atol
+    log(f"kernels: {name}{label} shape {tuple(out.shape)} max-abs err {max_err:.3e} "
+        f"(max |ref| {float(np.max(np.abs(expect))):.3e}; atol {atol:g}, rtol {rtol:g}) "
+        f"{'ok' if ok else 'FAIL'}")
+    return ok
+
+
+def ssd_vjp_ok(*, batch: int, seq: int, seed: int) -> bool:
+    """The SSD kernel pair's gradient (dx, d dt, d a, dB, dC), with
+    cotangents on the output and the final state, against autodiff of the
+    token-by-token reference.
+
+    The reference runs in float64 on the host: in float32 its own rounding
+    in d a, a sum over every position of values up to about 2e3, reaches
+    1.6e-3 against float64 (CPU, 1 x 2048 tokens), above the tolerance."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import ops, ref
+
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    args = (jax.random.normal(k[0], (batch, seq, 32, 64)) * 0.5,
+            jax.random.uniform(k[1], (batch, seq, 32), minval=0.01, maxval=0.2),
+            -jnp.exp(jax.random.uniform(k[2], (32,), minval=-2.0, maxval=1.0)),
+            jax.random.normal(k[3], (batch, seq, 1, 128)) * 0.5,
+            jax.random.normal(k[4], (batch, seq, 1, 128)) * 0.5)
+    cotangents = (jax.random.normal(k[5], (batch, seq, 32, 64)),
+                  jax.random.normal(k[6], (batch, 32, 64, 128)))
+
+    def grads(scan, cts):
+        return jax.jit(lambda *z: jax.vjp(scan, *z)[1](cts))
+
+    compiled = grads(lambda *z: ops.ssd_chunk_scan(*z, chunk=64), cotangents).lower(*args).compile()
+    n_kernels = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    check(n_kernels == 2, f"ssd_vjp: expected the forward and backward kernels, found {n_kernels}")
+    got = jax.block_until_ready(compiled(*args))
+    cpu = jax.devices("cpu")[0]
+    with jax.enable_x64(True):
+        wide = [jax.device_put(np.asarray(v, np.float64), cpu) for v in (*args, *cotangents)]
+        want = grads(ref.ssd_scan_ref, tuple(wide[5:]))(*wide[:5])
+        want = [np.asarray(v) for v in want]
+    ok = True
+    for name, out, expect in zip(("dx", "ddt", "da", "dB", "dC"), got, want):
+        ok = within("ssd_vjp", out, expect, f" {name}") and ok
+    return ok
+
+
 def phase_kernels(*, seq: int) -> None:
     import functools
 
     import jax
-    import jax.numpy as jnp
 
     failed = []
     for name, kernel, kernel_kw, reference, ref_kw, args in kernel_cases(seq, SEED):
@@ -194,21 +258,14 @@ def phase_kernels(*, seq: int) -> None:
         check("tpu_custom_call" in compiled.as_text(),
               f"{name}: no Mosaic kernel in the compiled program")
         # run the very program that was checked, not a second compile of it
-        out = _output(jax.block_until_ready(compiled(*args))).astype(jnp.float32)
+        out = _output(jax.block_until_ready(compiled(*args)))
         with jax.default_matmul_precision("highest"):
             expect = jax.block_until_ready(jax.jit(functools.partial(reference, **ref_kw))(*args))
-        expect = _output(expect).astype(jnp.float32)
-        atol, rtol = KERNEL_TOL[name]
-        err = jnp.abs(out - expect)
-        max_err = float(jnp.max(err))
-        excess = float(jnp.max(err - rtol * jnp.abs(expect)))
-        ok = bool(jnp.all(jnp.isfinite(out))) and excess <= atol
-        log(f"kernels: {name} shape {tuple(out.shape)} max-abs err {max_err:.3e} "
-            f"(max |ref| {float(jnp.max(jnp.abs(expect))):.3e}; atol {atol:g}, rtol {rtol:g}) "
-            f"{'ok' if ok else 'FAIL'}")
-        if not ok:
+        if not within(name, out, _output(expect)):
             failed.append(name)
-        del out, expect, err
+        del out, expect
+    if not ssd_vjp_ok(**SSD_VJP_SHAPE, seed=SEED):
+        failed.append("ssd_vjp")
     check(not failed, f"kernels outside tolerance: {failed}")
 
 

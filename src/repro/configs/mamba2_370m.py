@@ -20,6 +20,7 @@ CONFIG = ModelConfig(
     ssm=SSMConfig(d_inner=2048, head_dim=64, d_state=128, n_groups=1, conv_width=4, chunk=64),
     tie_embeddings=True,
     sub_quadratic=True,  # O(1) SSM state
+    use_scan_kernels=True,  # train the SSD scan through the Pallas kernel pair
 )
 
 SMOKE = ModelConfig(

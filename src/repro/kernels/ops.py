@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from .flash_attention import flash_attention_bhsd
 from .rg_lru import rg_lru_scan_blocked
-from .ssd import ssd_chunk_scan_blocked
+from .ssd import ssd_chunk_scan_bwd, ssd_chunk_scan_fwd
 
 
 def _interpret() -> bool:
@@ -66,6 +66,29 @@ def rg_lru_scan(a: jax.Array, bx: jax.Array, *, block_t: int = 16, block_n: int 
     return rg_lru_scan_blocked(a, bx, block_t=block_t, block_n=block_n, interpret=_interpret())
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _ssd_scan(x, dt, a, b_in, c_in, chunk):
+    y, final, _ = ssd_chunk_scan_fwd(x, dt, a, b_in, c_in, chunk=chunk, save_states=False,
+                                     interpret=_interpret())
+    return y, final
+
+
+def _ssd_scan_fwd(x, dt, a, b_in, c_in, chunk):
+    y, final, res = ssd_chunk_scan_fwd(x, dt, a, b_in, c_in, chunk=chunk, save_states=True,
+                                       interpret=_interpret())
+    return (y, final), res
+
+
+def _ssd_scan_bwd(chunk, res, cotangents):
+    dy, dfinal = cotangents
+    return ssd_chunk_scan_bwd(res, dy, dfinal, chunk=chunk, interpret=_interpret())
+
+
+# Under remat the primal pass then runs the forward without the chunk
+# states, and the backward pass runs the forward that keeps them.
+_ssd_scan.defvjp(_ssd_scan_fwd, _ssd_scan_bwd, optimize_remat=True)
+
+
 @partial(jax.jit, static_argnames=("chunk",))
 def ssd_chunk_scan(
     x: jax.Array,
@@ -75,8 +98,9 @@ def ssd_chunk_scan(
     c_in: jax.Array,
     *,
     chunk: int = 64,
-) -> Tuple[jax.Array, None]:
-    """Fused SSD chunk scan; returns (y, None) — final state is kept device-
-    side by the prefill path via the reference implementation."""
-    y = ssd_chunk_scan_blocked(x, dt, a, b_in, c_in, chunk=chunk, interpret=_interpret())
-    return y, None
+) -> Tuple[jax.Array, jax.Array]:
+    """Fused SSD chunk scan: y (B, S, H, P) and the final state (B, H, P, N)
+    from x (B, S, H, P), dt (B, S, H), a (H,), B and C (B, S, G, N), all
+    fp32.  Differentiable: the gradient is a second Pallas kernel, a reverse
+    sweep over the chunks."""
+    return _ssd_scan(x, dt, a, b_in, c_in, chunk)

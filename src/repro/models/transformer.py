@@ -163,6 +163,9 @@ def block_apply(
     """Full-sequence block (train / prefill compute). Returns (x, aux)."""
     mixer, _ = spec
     hd = cfg.resolved_head_dim
+    # Mosaic kernels cannot be partitioned automatically: a step sharded
+    # over a mesh keeps the XLA scans.
+    scan_kernels = cfg.use_scan_kernels and cfg.act_pspec is None
     h = norm_apply(cfg, p["norm1"], x)
     if mixer in ("attn", "attn_local", "attn_bidir"):
         window = cfg.window if mixer == "attn_local" else None
@@ -180,12 +183,12 @@ def block_apply(
                              positions=positions, chunk_q=cfg.attn_chunk_q,
                              act_pspec=cfg.act_pspec)
     elif mixer == "rglru":
-        out = rglru_mod.rglru_apply(p["mixer"], h, use_kernel=cfg.use_scan_kernels)
+        out = rglru_mod.rglru_apply(p["mixer"], h, use_kernel=scan_kernels)
     elif mixer == "ssd":
         s = cfg.ssm
         out = ssd_mod.ssd_apply(p["mixer"], h, d_inner=s.d_inner, head_dim=s.head_dim,
                                 d_state=s.d_state, n_groups=s.n_groups, chunk=s.chunk,
-                                use_kernel=cfg.use_scan_kernels)
+                                use_kernel=scan_kernels)
     else:
         raise ValueError(mixer)
     x = x + out

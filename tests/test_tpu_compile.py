@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.rg_lru import rg_lru_scan_blocked
-from repro.kernels.ssd import ssd_chunk_scan_blocked
+from repro.kernels.ssd import ssd_chunk_scan_bwd, ssd_chunk_scan_fwd
 
 SEQ = 4096
 
@@ -79,14 +79,36 @@ def test_rg_lru_compiles_for_v5e(one_chip):
     _compile_text(fn, one_chip, ((1, SEQ, 2560), jnp.float32), ((1, SEQ, 2560), jnp.float32))
 
 
+def _ssd_shapes(batch, seq):
+    # mamba2-370m: 32 heads x 64, d_state 128, one B/C group
+    return (((batch, seq, 32, 64), jnp.float32), ((batch, seq, 32), jnp.float32),
+            ((32,), jnp.float32), ((batch, seq, 1, 128), jnp.float32),
+            ((batch, seq, 1, 128), jnp.float32))
+
+
 def test_ssd_compiles_for_v5e(one_chip):
-    # mamba2-370m: 32 heads x 64, d_state 128, one B/C group, chunk 64
-    fn = functools.partial(ssd_chunk_scan_blocked, chunk=64, interpret=False)
-    _compile_text(
-        fn, one_chip,
-        ((1, SEQ, 32, 64), jnp.float32),
-        ((1, SEQ, 32), jnp.float32),
-        ((32,), jnp.float32),
-        ((1, SEQ, 1, 128), jnp.float32),
-        ((1, SEQ, 1, 128), jnp.float32),
-    )
+    def fwd(*args):
+        return ssd_chunk_scan_fwd(*args, chunk=64, save_states=False, interpret=False)[:2]
+
+    _compile_text(fwd, one_chip, *_ssd_shapes(1, SEQ))
+
+
+def _ssd_fwd_states(*args):
+    return ssd_chunk_scan_fwd(*args, chunk=64, save_states=True, interpret=False)
+
+
+def test_ssd_forward_under_vjp_compiles_for_v5e(one_chip):
+    # the mamba2-370m train cell: batch 8 x 2048
+    _compile_text(_ssd_fwd_states, one_chip, *_ssd_shapes(8, 2048))
+
+
+def test_ssd_backward_compiles_for_v5e(one_chip):
+    shapes = _ssd_shapes(8, 2048)
+    y, final, res = jax.eval_shape(
+        _ssd_fwd_states, *(jax.ShapeDtypeStruct(s, d) for s, d in shapes))
+
+    def bwd(*args):
+        *res, dy, dfinal = args
+        return ssd_chunk_scan_bwd(tuple(res), dy, dfinal, chunk=64, interpret=False)
+
+    _compile_text(bwd, one_chip, *((v.shape, v.dtype) for v in (*res, y, final)))
