@@ -97,10 +97,15 @@ class ModelConfig:
     logit_softcap: Optional[float] = None
     tie_embeddings: bool = False
     embed_scale: bool = False  # gemma: embeddings * sqrt(d_model)
+    # Granite-style (muP) multipliers; 1.0 / None leave the model as it was.
+    embed_multiplier: float = 1.0  # embeddings * this
+    residual_multiplier: float = 1.0  # each sublayer's output * this before the residual add
+    logits_divisor: float = 1.0  # logits / this
+    attn_scale: Optional[float] = None  # softmax scale; None = 1 / sqrt(head_dim)
     activation: str = "silu"
     norm_type: str = "rms"  # rms | layer (whisper)
     gated_mlp: bool = True  # False: plain w1/gelu/w2 (whisper)
-    pos_embed: str = "rope"  # rope | learned (whisper)
+    pos_embed: str = "rope"  # rope | learned (whisper) | none (NoPE: no rotary in attention)
     max_pos: int = 32_768  # learned-position table size
     norm_eps: float = 1e-6
     # sub-configs

@@ -9,6 +9,7 @@ from . import (
     deepseek_moe_16b,
     deepseek_v2_236b,
     gemma3_12b,
+    granite_4_0_h_micro,
     mamba2_370m,
     mistral_nemo_12b,
     paligemma_3b,
@@ -29,6 +30,7 @@ _MODULES = {
     "deepseek-v2-236b": deepseek_v2_236b,
     "mamba2-370m": mamba2_370m,
     "whisper-large-v3": whisper_large_v3,
+    "granite-4.0-h-micro": granite_4_0_h_micro,
 }
 
 ARCHS: Tuple[str, ...] = tuple(_MODULES)

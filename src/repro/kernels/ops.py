@@ -28,7 +28,7 @@ def _interpret() -> bool:
     return backend == "cpu"
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))
+@partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k", "scale"))
 def flash_attention(
     q: jax.Array,  # (B, S, H, d)
     k: jax.Array,  # (B, T, K, d)
@@ -38,8 +38,10 @@ def flash_attention(
     window: Optional[int] = None,
     block_q: int = 128,
     block_k: int = 128,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """FlashAttention with GQA; returns (B, S, H, d)."""
+    """FlashAttention with GQA; returns (B, S, H, d). ``scale``: the softmax
+    scale, 1 / sqrt(d) when None."""
     b, s, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
     qb = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -50,7 +52,7 @@ def flash_attention(
         kb,
         vb,
         n_q_per_kv=h // kh,
-        scale=1.0 / math.sqrt(d),
+        scale=1.0 / math.sqrt(d) if scale is None else scale,
         causal=causal,
         window=window,
         block_q=block_q,

@@ -170,6 +170,10 @@ def _sdpa_q_chunked(
     return out.transpose(1, 0, 2, 3, 4, 5).reshape(b, s, kh, g, h)
 
 
+def _softmax_scale(scale: Optional[float], head_dim: int) -> float:
+    return 1.0 / math.sqrt(head_dim) if scale is None else scale
+
+
 def gqa_apply(
     params: Params,
     x: jax.Array,
@@ -177,37 +181,41 @@ def gqa_apply(
     n_heads: int,
     n_kv_heads: int,
     head_dim: int,
-    rope_theta: float = 10000.0,
+    rope_theta: Optional[float] = 10000.0,
     causal: bool = True,
     window: Optional[int] = None,
     positions: Optional[jax.Array] = None,
     chunk_q: int = 0,
     use_flash_kernel: bool = False,
     act_pspec=None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Self-attention over a full sequence (training / prefill)."""
+    """Self-attention over a full sequence (training / prefill).
+    ``rope_theta=None``: no rotary embedding (NoPE); ``scale=None``: the
+    softmax scale is 1 / sqrt(head_dim)."""
     dtype = x.dtype
     b, s, d = x.shape
     g = n_heads // n_kv_heads
     with scope("attn_proj"):
         q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
-        if positions is None:
-            positions = jnp.arange(s)
-        cos, sin = rope_tables(positions, head_dim, rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if rope_theta is not None:
+            if positions is None:
+                positions = jnp.arange(s)
+            cos, sin = rope_tables(positions, head_dim, rope_theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         if act_pspec is not None:
             b_ax, s_ax = act_pspec
             q = _constrain(q, (b_ax, s_ax, None, None))  # query: SP over seq
             k = _constrain(k, (b_ax, None, None, None))  # K/V: gathered once
             v = _constrain(v, (b_ax, None, None, None))
-        scale = 1.0 / math.sqrt(head_dim)
+        scale = _softmax_scale(scale, head_dim)
         with scope("attn_core"):
             if use_flash_kernel:
                 from repro.kernels import ops as _kops
 
                 out = _kops.flash_attention(
-                    q, k, v, causal=causal, window=window
+                    q, k, v, causal=causal, window=window, scale=scale
                 ).reshape(b, s, n_kv_heads, g, head_dim)
             else:
                 q = q.reshape(b, s, n_kv_heads, g, head_dim)
@@ -277,7 +285,7 @@ def gqa_prefill_cache(
     n_heads: int,
     n_kv_heads: int,
     head_dim: int,
-    rope_theta: float = 10000.0,
+    rope_theta: Optional[float] = 10000.0,
     window: Optional[int] = None,
     cache_dtype=None,
 ) -> Params:
@@ -289,9 +297,9 @@ def gqa_prefill_cache(
     b, s, _ = x.shape
     dtype = cache_dtype or x.dtype
     _, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
-    positions = jnp.arange(s)
-    cos, sin = rope_tables(positions, head_dim, rope_theta)
-    k = apply_rope(k, cos, sin)
+    if rope_theta is not None:
+        cos, sin = rope_tables(jnp.arange(s), head_dim, rope_theta)
+        k = apply_rope(k, cos, sin)
     if window is not None and window < max_len:
         w = window
         cache = gqa_cache_init(b, w, n_kv_heads, head_dim, dtype)
@@ -318,17 +326,18 @@ def gqa_decode(
     n_heads: int,
     n_kv_heads: int,
     head_dim: int,
-    rope_theta: float = 10000.0,
+    rope_theta: Optional[float] = 10000.0,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> Tuple[jax.Array, Params]:
     dtype = x.dtype
     b = x.shape[0]
     g = n_heads // n_kv_heads
     q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
-    pos = jnp.asarray(index)[None]
-    cos, sin = rope_tables(pos, head_dim, rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if rope_theta is not None:
+        cos, sin = rope_tables(jnp.asarray(index)[None], head_dim, rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     t = cache["k"].shape[1]
     if window is not None and t <= window:
@@ -348,7 +357,7 @@ def gqa_decode(
         mask = jnp.where(j <= index, 0.0, NEG_INF).astype(jnp.float32)[None, :]
 
     q = q.reshape(b, 1, n_kv_heads, g, head_dim)
-    out = _sdpa(q, ck.astype(dtype), cv.astype(dtype), mask, 1.0 / math.sqrt(head_dim))
+    out = _sdpa(q, ck.astype(dtype), cv.astype(dtype), mask, _softmax_scale(scale, head_dim))
     out = out.reshape(b, 1, n_heads * head_dim)
     out = jnp.einsum("bsh,hd->bsd", out, params["wo"].astype(dtype))
     return out, {"k": ck, "v": cv}

@@ -107,9 +107,13 @@ def embed_lookup(embedding: jax.Array, tokens: jax.Array, dtype=jnp.bfloat16) ->
     return jnp.take(embedding, tokens, axis=0).astype(dtype)
 
 
-def lm_logits(x: jax.Array, head: jax.Array, softcap: Optional[float] = None) -> jax.Array:
-    """x: (..., D) @ head (D, V) -> fp32 logits with optional soft-capping."""
+def lm_logits(x: jax.Array, head: jax.Array, softcap: Optional[float] = None,
+              divisor: float = 1.0) -> jax.Array:
+    """x: (..., D) @ head (D, V) -> fp32 logits, divided by ``divisor``, with
+    optional soft-capping."""
     logits = jnp.einsum("...d,dv->...v", x, head.astype(x.dtype)).astype(jnp.float32)
+    if divisor != 1.0:
+        logits = logits / divisor
     if softcap is not None:
         logits = jnp.tanh(logits / softcap) * softcap
     return logits
@@ -128,6 +132,7 @@ def chunked_cross_entropy(
     labels: jax.Array,
     n_chunks: int = 8,
     softcap: Optional[float] = None,
+    divisor: float = 1.0,
 ) -> jax.Array:
     """Cross entropy without materializing full (B, S, V) logits.
 
@@ -147,7 +152,7 @@ def chunked_cross_entropy(
     @jax.checkpoint
     def body(total, xs):
         xi, li = xs
-        logits = lm_logits(xi, head, softcap)
+        logits = lm_logits(xi, head, softcap, divisor)
         return total + jnp.sum(softmax_cross_entropy(logits, li)), None
 
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xc, lc))

@@ -34,7 +34,15 @@ from .layers import (
     lm_logits,
     softmax_cross_entropy,
 )
-from .transformer import block_apply, norm_apply, norm_init, stack_init
+from .transformer import (
+    MIXER_SCOPE,
+    block_apply,
+    layer_theta,
+    norm_apply,
+    norm_init,
+    residual_add,
+    stack_init,
+)
 
 Params = Dict[str, Any]
 
@@ -89,6 +97,8 @@ def _embed_tokens(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Ar
         x = embed_lookup(params["embed"], tokens)
         if cfg.embed_scale:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        if cfg.embed_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embed_multiplier, x.dtype)
         return x
 
 
@@ -151,9 +161,10 @@ def lm_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]) -> Tu
             hidden = hidden[:, -labels.shape[1] :]  # loss over text positions only
         head = _head_matrix(cfg, params)
         if cfg.chunked_loss_chunks > 1:
-            ce = chunked_cross_entropy(hidden, head, labels, cfg.chunked_loss_chunks, cfg.logit_softcap)
+            ce = chunked_cross_entropy(hidden, head, labels, cfg.chunked_loss_chunks, cfg.logit_softcap,
+                                       cfg.logits_divisor)
         else:
-            logits = lm_logits(hidden, head, cfg.logit_softcap)
+            logits = lm_logits(hidden, head, cfg.logit_softcap, cfg.logits_divisor)
             ce = jnp.mean(softmax_cross_entropy(logits, labels))
         aux_w = cfg.moe.aux_weight if cfg.moe is not None else 0.0
         loss = ce + aux_w * aux
@@ -204,7 +215,7 @@ def _block_prefill(cfg, spec, p, x, max_len, enc):
         window = cfg.window if mixer == "attn_local" else None
         cache = attn.gqa_prefill_cache(
             p["mixer"], h, max_len, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=hd, rope_theta=_theta(cfg, mixer), window=window,
+            head_dim=hd, rope_theta=layer_theta(cfg, mixer), window=window,
             cache_dtype=kv_dtype)
     elif mixer == "mla":
         m = cfg.mla
@@ -226,12 +237,6 @@ def _block_prefill(cfg, spec, p, x, max_len, enc):
     return x, aux, cache
 
 
-def _theta(cfg: ModelConfig, mixer: str) -> float:
-    if mixer == "attn_local" and cfg.rope_theta_local is not None:
-        return cfg.rope_theta_local
-    return cfg.rope_theta
-
-
 def _block_decode(cfg, spec, p, cache, x, index):
     mixer, _ = spec
     hd = cfg.resolved_head_dim
@@ -242,7 +247,8 @@ def _block_decode(cfg, spec, p, cache, x, index):
         window = cfg.window if mixer == "attn_local" else None
         out, core = attn.gqa_decode(
             p["mixer"], h, core, index, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=hd, rope_theta=_theta(cfg, mixer), window=window)
+            head_dim=hd, rope_theta=layer_theta(cfg, mixer), window=window,
+            scale=cfg.attn_scale)
     elif mixer == "mla":
         m = cfg.mla
         out, core = attn.mla_decode(
@@ -258,7 +264,7 @@ def _block_decode(cfg, spec, p, cache, x, index):
             d_state=s.d_state, n_groups=s.n_groups)
     else:
         raise ValueError(mixer)
-    x = x + out
+    x = residual_add(cfg, MIXER_SCOPE.get(mixer), x, out)
     if cross:
         hx = norm_apply(cfg, p["norm_x"], x)
         x = x + attn.cross_attention_apply(
@@ -321,7 +327,7 @@ def prefill(
         cache["tail"].append(c)
 
     x = norm_apply(cfg, params["final_norm"], x)
-    logits = lm_logits(x[:, -1:], _head_matrix(cfg, params), cfg.logit_softcap)
+    logits = lm_logits(x[:, -1:], _head_matrix(cfg, params), cfg.logit_softcap, cfg.logits_divisor)
     cache["index"] = jnp.asarray(tokens.shape[1] + (patches.shape[1] if patches is not None else 0), jnp.int32)
     return logits, cache
 
@@ -397,6 +403,6 @@ def decode_step(
         new_cache["tail"].append(c)
 
     x = norm_apply(cfg, params["final_norm"], x)
-    logits = lm_logits(x, _head_matrix(cfg, params), cfg.logit_softcap)
+    logits = lm_logits(x, _head_matrix(cfg, params), cfg.logit_softcap, cfg.logits_divisor)
     new_cache["index"] = index + 1
     return logits, new_cache

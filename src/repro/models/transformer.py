@@ -7,7 +7,8 @@ Design notes
   keeps 512-device lowering fast for 60-layer models.
 * Heterogeneous stacks (gemma3's 5 local : 1 global, recurrentgemma's
   R,R,A) are expressed inside the pattern, so the scan body stays static.
-* ``remat`` wraps the scanned group body in ``jax.checkpoint``.
+* ``remat`` wraps the scanned group body in ``jax.checkpoint``, or each
+  block of it where the pattern has several.
 """
 
 from __future__ import annotations
@@ -125,10 +126,26 @@ def block_init(key, cfg: ModelConfig, spec: Tuple[str, str], cross: bool = False
     return p
 
 
-def _layer_theta(cfg: ModelConfig, mixer: str) -> float:
+def layer_theta(cfg: ModelConfig, mixer: str) -> Optional[float]:
+    """The rotary base of an attention layer; None where it has no rotary."""
+    if cfg.pos_embed == "none":
+        return None
     if mixer == "attn_local" and cfg.rope_theta_local is not None:
         return cfg.rope_theta_local
     return cfg.rope_theta
+
+
+# The scope a mixer's output is scaled in before the residual add.
+MIXER_SCOPE = {"attn": "attn_proj", "attn_local": "attn_proj", "attn_bidir": "attn_proj", "ssd": "ssd_proj"}
+
+
+def residual_add(cfg: ModelConfig, scope_name: Optional[str], x: jax.Array, out: jax.Array) -> jax.Array:
+    """x + out, the sublayer's output first scaled by ``residual_multiplier``
+    inside the sublayer's own scope."""
+    if cfg.residual_multiplier != 1.0:
+        with scope(scope_name or "layer_stack"):
+            out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+    return x + out
 
 
 def _ffn_apply(cfg: ModelConfig, spec: Tuple[str, str], p: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -144,11 +161,11 @@ def _ffn_apply(cfg: ModelConfig, spec: Tuple[str, str], p: Params, x: jax.Array)
                 out = mlp_apply(p["ffn"], h, cfg.activation)
             else:
                 out = plain_mlp_apply(p["ffn"], h)
-        return x + out, zero
+        return residual_add(cfg, "mlp", x, out), zero
     m = cfg.moe
     out, aux = moe_mod.moe_apply(p["ffn"], h, n_experts=m.n_experts, top_k=m.top_k,
                                  capacity_factor=m.capacity_factor, group_size=m.group_size)
-    return x + out, aux
+    return residual_add(cfg, None, x, out), aux
 
 
 def block_apply(
@@ -171,9 +188,10 @@ def block_apply(
         window = cfg.window if mixer == "attn_local" else None
         out = attn.gqa_apply(
             p["mixer"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=hd,
-            rope_theta=_layer_theta(cfg, mixer), causal=(mixer != "attn_bidir"),
+            rope_theta=layer_theta(cfg, mixer), causal=(mixer != "attn_bidir"),
             window=window, positions=positions, chunk_q=cfg.attn_chunk_q,
-            use_flash_kernel=cfg.use_flash_kernel, act_pspec=cfg.act_pspec)
+            use_flash_kernel=cfg.use_flash_kernel, act_pspec=cfg.act_pspec,
+            scale=cfg.attn_scale)
     elif mixer == "mla":
         m = cfg.mla
         out = attn.mla_apply(p["mixer"], h, n_heads=cfg.n_heads,
@@ -191,7 +209,7 @@ def block_apply(
                                 use_kernel=scan_kernels)
     else:
         raise ValueError(mixer)
-    x = x + out
+    x = residual_add(cfg, MIXER_SCOPE.get(mixer), x, out)
     if "cross" in p and enc_kv is not None:
         hx = norm_apply(cfg, p["norm_x"], x)
         x = x + attn.cross_attention_apply(p["cross"], hx, enc_kv, n_heads=cfg.n_heads,
@@ -273,18 +291,24 @@ def stack_apply(
         aux = aux + a
 
     if cfg.n_groups > 0:
+        # A group of several blocks is recomputed a block at a time, so the
+        # backward pass holds one block's intermediates, not the group's.
+        per_block = len(cfg.pattern) > 1
+
         def group_body(carry, group_params):
             x, aux = carry
             for j, spec in enumerate(cfg.pattern):
                 p = group_params[f"p{j}"]
-                x, a = block_apply(cfg, spec, p, x,
-                                   enc_kv=_cross_kv_for(cfg, p, enc_out),
-                                   positions=positions)
+                apply = partial(block_apply, cfg, spec, enc_kv=_cross_kv_for(cfg, p, enc_out),
+                                positions=positions)
+                if per_block:
+                    apply = _maybe_remat(cfg, apply)
+                x, a = apply(p, x)
                 x = constrain_acts(cfg, x)
                 aux = aux + a
             return (x, aux), None
 
-        body = _maybe_remat(cfg, group_body)
+        body = group_body if per_block else _maybe_remat(cfg, group_body)
         with scope("layer_stack"):
             if cfg.scan_layers:
                 (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])

@@ -49,6 +49,7 @@ def test_full_config_exactness(arch):
         "deepseek-v2-236b": (60, 5120, 128, 128, 1536, 102400),
         "mamba2-370m": (48, 1024, 1, 1, 0, 50280),
         "whisper-large-v3": (64, 1280, 20, 20, 5120, 51866),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }[arch]
     n_layers, d_model, n_heads, n_kv, d_ff, vocab = spec
     assert cfg.n_layers == n_layers
@@ -67,6 +68,10 @@ def test_full_config_exactness(arch):
         assert cfg.ssm is not None and cfg.ssm.d_state == 128
     else:
         assert cfg.d_ff == d_ff
+    if arch == "granite-4.0-h-micro":
+        kinds = [mixer for mixer, _ in cfg.layer_specs]
+        assert [i for i, k in enumerate(kinds) if k == "attn"] == [5, 15, 25, 35]
+        assert (cfg.ssm.d_inner, cfg.ssm.head_dim, cfg.ssm.d_state) == (4096, 64, 128)
 
 
 @pytest.mark.parametrize("arch", ARCHS)

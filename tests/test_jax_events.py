@@ -127,6 +127,10 @@ def _scope_in(name: str, op_name: str) -> bool:
     ("mistral-nemo-12b",
      ("embed", "norm", "layer_stack", "attn_proj", "attn_core", "mlp", "head_loss", "optimizer"),
      "attn_core"),
+    ("granite-4.0-h-micro",
+     ("embed", "norm", "layer_stack", "ssd_proj", "ssd_scan", "attn_proj", "attn_core", "mlp",
+      "head_loss", "optimizer"),
+     "attn_core"),
 ])
 def test_train_step_hlo_names_every_layer_scope(arch, scopes, rematted):
     from repro.configs import get_smoke_config
