@@ -20,8 +20,8 @@ Phases on one chip:
            1024, 32 generated tokens
   kernels  the Pallas kernels from ``kernels/ops.py``, compiled for the chip
            at real widths, against the plain references in ``kernels/ref.py``;
-           the SSD scan's gradient (its backward kernel) too, against
-           autodiff of the reference
+           the SSD scan's and flash attention's gradients (their backward
+           kernels) too, against autodiff of the references
 
 With ``--four-chips`` only the sharded path runs: the ``--mesh`` train path
 (data 1 x model 4) and, for comparison, the same config, seed and batch on
@@ -63,7 +63,11 @@ KERNEL_TOL = {
     "rg_lru": (1e-4, 1e-4),
     "ssd": (1e-3, 0.0),
     "ssd_vjp": (1e-3, 0.0),
+    "flash_vjp": (2e-2, 2e-2),
 }
+# The flash gradient's head shapes: (query heads, KV heads, head_dim) of
+# mistral-nemo-12b and of granite-4.0-h-micro (two heads a lane group).
+FLASH_VJP_HEADS = {"mistral": (32, 8, 128), "granite": (32, 8, 64)}
 # The SSD gradient's shapes: the reference's autodiff keeps the (P, N)
 # state of every position, 1 MiB each at one sequence of 32 heads.
 SSD_VJP_SHAPE = dict(batch=1, seq=2048)
@@ -247,6 +251,38 @@ def ssd_vjp_ok(*, batch: int, seq: int, seed: int) -> bool:
     return ok
 
 
+def flash_vjp_ok(*, seq: int, seed: int) -> bool:
+    """The flash kernels' gradient (dq, dk, dv) of one bfloat16 sequence
+    at each cell's head shape, against autodiff of the naive reference in
+    float32 at "highest" matmul precision.  The kernels round the
+    probabilities and dS to bfloat16 for their matmuls, as the XLA path
+    does."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import ops, ref
+
+    ok = True
+    for name, (h, kh, d) in FLASH_VJP_HEADS.items():
+        k = jax.random.split(jax.random.PRNGKey(seed), 4)
+        q, kk, v, do = (jax.random.normal(key, (1, seq, n, d)).astype(jnp.bfloat16)
+                        for key, n in zip(k, (h, kh, kh, h)))
+
+        def grads(attend):
+            return jax.jit(lambda *z: jax.vjp(attend, *z)[1](do.astype(z[0].dtype)))
+
+        compiled = grads(ops.flash_attention).lower(q, kk, v).compile()
+        names = [n for n in ("fwd", "bwd_dkv", "bwd_dq") if f"flash_attention_{n}" in compiled.as_text()]
+        check(len(names) == 3, f"flash_vjp {name}: expected the forward and both backward kernels, found {names}")
+        got = jax.block_until_ready(compiled(q, kk, v))
+        with jax.default_matmul_precision("highest"):
+            want = grads(ref.flash_attention_ref)(*(z.astype(jnp.float32) for z in (q, kk, v)))
+        for label, out, expect in zip(("dq", "dk", "dv"), got, want):
+            ok = within("flash_vjp", out, np.asarray(expect), f" {name} {label}") and ok
+    return ok
+
+
 def phase_kernels(*, seq: int) -> None:
     import functools
 
@@ -266,6 +302,8 @@ def phase_kernels(*, seq: int) -> None:
         del out, expect
     if not ssd_vjp_ok(**SSD_VJP_SHAPE, seed=SEED):
         failed.append("ssd_vjp")
+    if not flash_vjp_ok(seq=seq, seed=SEED):
+        failed.append("flash_vjp")
     check(not failed, f"kernels outside tolerance: {failed}")
 
 

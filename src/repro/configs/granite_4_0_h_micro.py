@@ -36,6 +36,7 @@ CONFIG = ModelConfig(
     logits_divisor=8.0,
     norm_eps=1e-5,
     use_scan_kernels=True,  # train the SSD scan through the Pallas kernel pair
+    use_flash_kernel=True,  # and attention through the flash kernel pair
 )
 
 SMOKE = ModelConfig(

@@ -17,6 +17,7 @@ CONFIG = ModelConfig(
     pattern=(("attn", "mlp"),),
     n_groups=40,
     rope_theta=1_000_000.0,
+    use_flash_kernel=True,  # train attention through the Pallas flash kernel pair
 )
 
 SMOKE = ModelConfig(

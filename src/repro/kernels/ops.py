@@ -16,7 +16,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import flash_attention_bhsd
+from .flash_attention import (
+    BWD_BLOCKS,
+    FWD_BLOCKS,
+    Spec,
+    default_block,
+    flash_attention_bwd,
+    flash_attention_fwd,
+)
 from .rg_lru import rg_lru_scan_blocked
 from .ssd import ssd_chunk_scan_bwd, ssd_chunk_scan_fwd
 
@@ -28,6 +35,25 @@ def _interpret() -> bool:
     return backend == "cpu"
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, spec, bwd_spec):
+    return flash_attention_fwd(q, k, v, spec, with_lse=False, interpret=_interpret())[0]
+
+
+def _flash_fwd(q, k, v, spec, bwd_spec):
+    o, lse = flash_attention_fwd(q, k, v, spec, with_lse=True, interpret=_interpret())
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bwd(spec, bwd_spec, res, do):
+    return flash_attention_bwd(*res, do, bwd_spec, interpret=_interpret())
+
+
+# Under remat the primal pass then runs the forward without the
+# log-sum-exp, and the backward pass runs the forward that writes it.
+_flash.defvjp(_flash_fwd, _flash_bwd, optimize_remat=True)
+
+
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k", "scale"))
 def flash_attention(
     q: jax.Array,  # (B, S, H, d)
@@ -36,30 +62,27 @@ def flash_attention(
     *,
     causal: bool = True,
     window: Optional[int] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     scale: Optional[float] = None,
 ) -> jax.Array:
     """FlashAttention with GQA; returns (B, S, H, d). ``scale``: the softmax
-    scale, 1 / sqrt(d) when None."""
+    scale, 1 / sqrt(d) when None; ``block_q``/``block_k``: every kernel's
+    blocks, each kernel's preferred ones that divide the sequences when
+    None.  Differentiable: the gradient is a dK/dV and a dQ kernel."""
     b, s, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
-    qb = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    kb = k.transpose(0, 2, 1, 3).reshape(b * kh, t, d)
-    vb = v.transpose(0, 2, 1, 3).reshape(b * kh, t, d)
-    out = flash_attention_bhsd(
-        qb,
-        kb,
-        vb,
-        n_q_per_kv=h // kh,
-        scale=1.0 / math.sqrt(d) if scale is None else scale,
-        causal=causal,
-        window=window,
-        block_q=block_q,
-        block_k=block_k,
-        interpret=_interpret(),
-    )
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+
+    def spec(blocks):
+        return Spec(heads=h, kv_heads=kh, head_dim=d,
+                    scale=1.0 / math.sqrt(d) if scale is None else scale,
+                    causal=causal, window=window,
+                    block_q=block_q or default_block(s, blocks[0]),
+                    block_k=block_k or default_block(t, blocks[1]))
+
+    out = _flash(q.reshape(b, s, h * d), k.reshape(b, t, kh * d), v.reshape(b, t, kh * d),
+                 spec(FWD_BLOCKS), spec(BWD_BLOCKS))
+    return out.reshape(b, s, h, d)
 
 
 @partial(jax.jit, static_argnames=("block_t", "block_n"))

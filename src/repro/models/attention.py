@@ -211,12 +211,12 @@ def gqa_apply(
             v = _constrain(v, (b_ax, None, None, None))
         scale = _softmax_scale(scale, head_dim)
         with scope("attn_core"):
-            if use_flash_kernel:
+            # Mosaic kernels cannot be partitioned automatically: a step
+            # sharded over a mesh keeps the XLA path.
+            if use_flash_kernel and act_pspec is None:
                 from repro.kernels import ops as _kops
 
-                out = _kops.flash_attention(
-                    q, k, v, causal=causal, window=window, scale=scale
-                ).reshape(b, s, n_kv_heads, g, head_dim)
+                out = _kops.flash_attention(q, k, v, causal=causal, window=window, scale=scale)
             else:
                 q = q.reshape(b, s, n_kv_heads, g, head_dim)
                 if chunk_q and s > chunk_q and s % chunk_q == 0:

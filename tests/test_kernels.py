@@ -3,6 +3,8 @@
 Shape/dtype sweeps + hypothesis property tests per the assignment.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,6 +80,51 @@ def test_flash_attention_property(s, h, g, d, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=2e-5, atol=2e-5)
     # attention outputs are convex combinations of v rows
     assert float(jnp.max(jnp.abs(out))) <= float(jnp.max(jnp.abs(v))) + 1e-4
+
+
+def _flash_grads(attend, q, k, v, do):
+    out, vjp = jax.vjp(attend, q, k, v)
+    return vjp(do.astype(out.dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "s,h,kh,d,causal,window,blocks,remat",
+    [
+        (128, 8, 2, 64, True, None, (64, 64), False),  # GQA 4:1, two heads a lane group
+        (128, 8, 2, 128, True, None, (64, 64), False),  # GQA 4:1, one head a lane group
+        (128, 4, 1, 64, True, None, (64, 64), False),  # MQA
+        (256, 4, 2, 64, True, 64, (64, 64), False),  # sliding window
+        (128, 4, 2, 64, False, None, (64, 64), False),  # bidirectional
+        (256, 8, 2, 64, True, None, (128, 64), False),  # tiling: tall query blocks
+        (256, 8, 2, 64, True, 96, (64, 128), False),  # tiling: wide key blocks, window
+        (128, 8, 2, 64, True, None, (64, 64), True),  # under jax.checkpoint
+    ],
+    ids=["gqa-d64", "gqa-d128", "mqa", "window", "bidir", "tiling-q128", "tiling-k128",
+         "checkpoint"],
+)
+def test_flash_attention_grads_vs_ref(s, h, kh, d, causal, window, blocks, remat, dtype):
+    """dq, dk and dv of the kernel pair against autodiff of the reference."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(4), 4)
+    q = _rand(k1, (2, s, h, d), dtype)
+    k = _rand(k2, (2, s, kh, d), dtype)
+    v = _rand(k3, (2, s, kh, d), dtype)
+    do = _rand(k4, (2, s, h, d))
+    block_q, block_k = blocks
+
+    def kernel(q, k, v):
+        return ops.flash_attention(q, k, v, causal=causal, window=window,
+                                   block_q=block_q, block_k=block_k)
+
+    attend = jax.checkpoint(kernel) if remat else kernel
+    got = jax.jit(functools.partial(_flash_grads, attend))(q, k, v, do)
+    expect = _flash_grads(functools.partial(ref.flash_attention_ref, causal=causal, window=window),
+                          q, k, v, do)
+    rtol, atol = (2e-2, 2e-2) if dtype == jnp.bfloat16 else (2e-5, 2e-5)
+    for name, u, w in zip(("dq", "dk", "dv"), got, expect):
+        assert u.shape == w.shape and u.dtype == w.dtype, name
+        np.testing.assert_allclose(np.asarray(u, np.float32), np.asarray(w, np.float32),
+                                   rtol=rtol, atol=atol, err_msg=name)
 
 
 # ----------------------------------------------------------------------------
@@ -260,6 +307,41 @@ def test_ssd_kernel_only_in_unsharded_steps():
         with mesh:
             jaxpr = jax.make_jaxpr(lambda p, b: lm_loss(step_cfg, p, b)[0])(params, batch)
         assert ("pallas_call" in str(jaxpr)) == kernels
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_gqa_flash_kernel_matches_xla_path(head_dim):
+    """gqa_apply at SMOKE widths: the flash kernel pair gives the query-chunked
+    XLA path's output and parameter gradients within bfloat16 tolerance, and
+    a step sharded over a mesh (``act_pspec`` set) keeps the XLA path."""
+    from repro.models.attention import gqa_apply, gqa_init
+
+    params = gqa_init(jax.random.PRNGKey(0), 128, 4, 2, head_dim)
+    x = _rand(jax.random.PRNGKey(1), (2, 256, 128), jnp.bfloat16)
+    weight = _rand(jax.random.PRNGKey(2), (2, 256, 128))
+
+    def loss(p, x, flash, act_pspec=None):
+        out = gqa_apply(p, x, n_heads=4, n_kv_heads=2, head_dim=head_dim, rope_theta=1e6,
+                        chunk_q=64, use_flash_kernel=flash, act_pspec=act_pspec)
+        return jnp.sum(out.astype(jnp.float32) * weight), out
+
+    def run(flash):
+        return jax.jit(jax.value_and_grad(lambda p, x: loss(p, x, flash), has_aux=True))(params, x)
+
+    (_, out), grads = run(True)
+    (_, want_out), want_grads = run(False)
+    for name, u, w in [("out", out, want_out), *((n, grads[n], want_grads[n]) for n in want_grads)]:
+        u, w = np.asarray(u, np.float32), np.asarray(w, np.float32)
+        assert np.linalg.norm(u - w) <= 2e-2 * np.linalg.norm(w), name
+        np.testing.assert_allclose(u, w, rtol=0, atol=2e-2 * np.max(np.abs(w)), err_msg=name)
+
+    def step_jaxpr(act_pspec):
+        return str(jax.make_jaxpr(jax.grad(lambda p, x: loss(p, x, True, act_pspec)[0]))(params, x))
+
+    assert step_jaxpr(None).count("flash_attention_") == 3  # forward, dK/dV, dQ
+    with jax.make_mesh((1, 1), ("data", "model")):
+        sharded = step_jaxpr(("data", None))
+    assert "flash_attention" not in sharded and "pallas_call" not in sharded
 
 
 def test_ssd_kernel_exp_within_two_ulp():

@@ -19,7 +19,15 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.flash_attention import (
+    BWD_BLOCKS,
+    FWD_BLOCKS,
+    Spec,
+    default_block,
+    flash_attention_bwd_dkv,
+    flash_attention_bwd_dq,
+    flash_attention_fwd,
+)
 from repro.kernels.rg_lru import rg_lru_scan_blocked
 from repro.kernels.ssd import ssd_chunk_scan_bwd, ssd_chunk_scan_fwd
 
@@ -53,24 +61,43 @@ def _compile_text(fn, sharding, *shapes):
     return text
 
 
+def _flash_kernel(kernel, spec):
+    """One of the flash kernels as a function of its arrays."""
+    if kernel == "fwd":
+        return lambda q, k, v: flash_attention_fwd(q, k, v, spec, with_lse=False, interpret=False)[0]
+    if kernel == "fwd_lse":
+        return functools.partial(flash_attention_fwd, spec=spec, with_lse=True, interpret=False)
+    bwd = {"bwd_dkv": flash_attention_bwd_dkv, "bwd_dq": flash_attention_bwd_dq}[kernel]
+    return functools.partial(bwd, spec=spec, interpret=False)
+
+
+_FLASH_SHAPES = {
+    "gqa": (1, SEQ, 32, 8, 128, None),  # mistral-nemo-12b: GQA 4:1, causal
+    "windowed": (1, SEQ, 16, 8, 256, 1024),  # gemma3-12b local layers: sliding window
+    "gqa-d64": (1, 4 * SEQ, 32, 8, 64, None),  # granite-4.0-h-micro: two heads a lane group
+}
+
+
 @pytest.mark.parametrize(
-    "heads,kv_heads,head_dim,window",
-    [
-        (32, 8, 128, None),  # mistral-nemo-12b: GQA 4:1, causal
-        (16, 8, 256, 1024),  # gemma3-12b local layers: sliding window
-    ],
-    ids=["gqa", "windowed"],
+    "shape,kernel",
+    [("gqa", "fwd"), ("windowed", "fwd"),
+     *((shape, kernel) for shape in ("gqa", "gqa-d64")
+       for kernel in ("fwd_lse", "bwd_dkv", "bwd_dq")),
+     ("gqa-d64", "fwd")],
+    ids=["gqa", "windowed", "gqa-fwd-lse", "gqa-bwd-dkv", "gqa-bwd-dq", "gqa-d64-fwd-lse",
+         "gqa-d64-bwd-dkv", "gqa-d64-bwd-dq", "gqa-d64"],
 )
-def test_flash_attention_compiles_for_v5e(one_chip, heads, kv_heads, head_dim, window):
-    fn = functools.partial(
-        flash_attention_bhsd, n_q_per_kv=heads // kv_heads, scale=head_dim**-0.5,
-        causal=True, window=window, interpret=False)
-    _compile_text(
-        fn, one_chip,
-        ((heads, SEQ, head_dim), jnp.bfloat16),
-        ((kv_heads, SEQ, head_dim), jnp.bfloat16),
-        ((kv_heads, SEQ, head_dim), jnp.bfloat16),
-    )
+def test_flash_attention_compiles_for_v5e(one_chip, shape, kernel):
+    b, s, heads, kv_heads, head_dim, window = _FLASH_SHAPES[shape]
+    blocks = FWD_BLOCKS if kernel.startswith("fwd") else BWD_BLOCKS
+    spec = Spec(heads, kv_heads, head_dim, head_dim**-0.5, True, window,
+                default_block(s, blocks[0]), default_block(s, blocks[1]))
+    c = spec.kv_per_row
+    q, kv = ((b, s, heads * head_dim), jnp.bfloat16), ((b, s, kv_heads * head_dim), jnp.bfloat16)
+    rows = ((b, kv_heads // c, c * spec.group, s), jnp.float32)  # log-sum-exp, delta
+    shapes = (q, kv, kv) if kernel.startswith("fwd") else (q, kv, kv, q, rows, rows)
+    text = _compile_text(_flash_kernel(kernel, spec), one_chip, *shapes)
+    assert f"flash_attention_{kernel.removesuffix('_lse')}" in text
 
 
 def test_rg_lru_compiles_for_v5e(one_chip):
