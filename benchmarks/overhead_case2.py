@@ -8,7 +8,7 @@ and magnitude gap justify setprofile as the default instrumenter.
 
 Beyond-paper rows: sampling (the paper's future-work suggestion),
 sys.monitoring (PEP 669) and the adaptive PEP 669 epoch sampler quantify how
-much of the per-call beta is recoverable — EXPERIMENTS.md §Perf.
+much of the per-call beta is recoverable.
 
 Filtered-residual rows (``<inst>+filtered``) run the kernel with
 ``--filter=exclude:*`` — every region filtered, nothing recorded — so their

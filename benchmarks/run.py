@@ -1,5 +1,5 @@
-"""Benchmark orchestrator — one entry per paper table/figure + framework
-perf artifacts.  Prints ``name,us_per_call,derived`` CSV rows.
+"""Benchmark orchestrator — one entry per paper table/figure of monitor
+overhead.  Prints ``name,us_per_call,derived`` CSV rows.
 
     PYTHONPATH=src python -m benchmarks.run            # quick protocol
     PYTHONPATH=src REPRO_BENCH_FULL=1 python -m benchmarks.run   # 51 reps
@@ -10,13 +10,10 @@ Rows:
   event_buffer/*   : beyond-paper buffer-strategy cost (ns/event -> us)
   beta_inproc/*    : in-process per-call beta per instrumenter
   train_loop/*     : monitoring overhead around a jit train step
-  roofline/*       : summary rows from benchmarks/artifacts/roofline.json
-                     (produced by `python -m benchmarks.roofline`; cached)
 """
 
 from __future__ import annotations
 
-import json
 import os
 from typing import List, Tuple
 
@@ -68,33 +65,12 @@ def _rows_train_overhead() -> List[Row]:
     return rows
 
 
-def _rows_roofline() -> List[Row]:
-    path = os.path.join("benchmarks", "artifacts", "roofline.json")
-    rows: List[Row] = []
-    if not os.path.exists(path):
-        return [("roofline/missing", 0.0, "run `python -m benchmarks.roofline` first")]
-    with open(path) as fh:
-        recs = json.load(fh)
-    for r in recs:
-        if r.get("status") != "ok":
-            continue
-        rows.append(
-            (
-                f"roofline/{r['arch']}/{r['shape']}",
-                r["step_lower_bound_s"] * 1e6,
-                f"dom={r['dominant']};frac={r['roofline_fraction']:.3f}",
-            )
-        )
-    return rows
-
-
 def main() -> None:
     full = os.environ.get("REPRO_BENCH_FULL") == "1"
     rows: List[Row] = []
     rows += _rows_overhead(full)
     rows += _rows_event_throughput()
     rows += _rows_train_overhead()
-    rows += _rows_roofline()
     print()
     print("name,us_per_call,derived")
     for name, us, derived in rows:

@@ -1,4 +1,4 @@
-"""Assigned architecture configs + shape cells."""
+"""Architecture configs, by ``--arch`` name."""
 
 from .base import (  # noqa: F401
     EncoderConfig,
@@ -7,15 +7,10 @@ from .base import (  # noqa: F401
     ModelConfig,
     MoEConfig,
     RNNConfig,
-    SHAPE_CELLS,
     SSMConfig,
-    ShapeCell,
-    get_shape_cell,
 )
 from .registry import (  # noqa: F401
     ARCHS,
-    all_cells,
-    cell_applicable,
     get_config,
     get_smoke_config,
 )

@@ -2,8 +2,8 @@
 
 A model is a sequence of blocks described by *patterns*: ``head_pattern``
 (unscanned prologue), ``pattern`` repeated ``n_groups`` times (stacked
-params + ``jax.lax.scan`` — keeps HLO size and compile time flat in depth,
-essential at 512 devices), and ``tail_pattern`` (unscanned epilogue).
+params + ``jax.lax.scan`` — keeps HLO size and compile time flat in depth),
+and ``tail_pattern`` (unscanned epilogue).
 
 Block spec = (mixer, ffn):
   mixer: "attn" | "attn_local" | "mla" | "rglru" | "ssd" | "attn_bidir"
@@ -12,7 +12,7 @@ Block spec = (mixer, ffn):
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, replace
 from typing import Any, Optional, Tuple
 
 BlockSpec = Tuple[str, str]
@@ -121,13 +121,6 @@ class ModelConfig:
     # stack — halves FSDP all-gather wire bytes and gathered-weight buffers;
     # fp32 master weights live in the optimizer update (standard recipe).
     params_compute_dtype: str = "float32"  # float32 | bfloat16
-    # False: Python-loop over layer groups instead of lax.scan.  Used by the
-    # roofline harness at reduced depth so XLA's cost model sees every layer
-    # (scan bodies are costed once regardless of trip count).
-    scan_layers: bool = True
-    # decode KV-cache storage dtype; fp8 halves cache HBM reads vs bf16
-    # (per-tensor cast; scales would be per-block in a production fp8 path).
-    kv_cache_dtype: str = "bfloat16"  # bfloat16 | float8_e4m3fn
     use_flash_kernel: bool = False  # Pallas path (TPU target; interpret in tests)
     use_scan_kernels: bool = False  # Pallas rg_lru / ssd kernels
     attn_chunk_q: int = 512  # query-chunked attention; 0 = naive S^2 (baseline)
@@ -136,7 +129,6 @@ class ModelConfig:
     # with_sharding_constraint at block boundaries.  Set by the dist layer;
     # None on CPU/smoke paths (no mesh context).
     act_pspec: Optional[Tuple[Any, Any]] = None
-    sub_quadratic: bool = False  # eligible for long_500k cells
 
     # -- derived -------------------------------------------------------------
 
@@ -158,27 +150,3 @@ class ModelConfig:
     def scaled(self, **overrides) -> "ModelConfig":
         return replace(self, **overrides)
 
-
-@dataclass(frozen=True)
-class ShapeCell:
-    """One assigned (input-shape) cell."""
-
-    name: str  # train_4k | prefill_32k | decode_32k | long_500k
-    kind: str  # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPE_CELLS: Tuple[ShapeCell, ...] = (
-    ShapeCell("train_4k", "train", 4_096, 256),
-    ShapeCell("prefill_32k", "prefill", 32_768, 32),
-    ShapeCell("decode_32k", "decode", 32_768, 128),
-    ShapeCell("long_500k", "decode", 524_288, 1),
-)
-
-
-def get_shape_cell(name: str) -> ShapeCell:
-    for cell in SHAPE_CELLS:
-        if cell.name == name:
-            return cell
-    raise KeyError(name)

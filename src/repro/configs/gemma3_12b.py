@@ -28,7 +28,6 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     embed_scale=True,
     activation="gelu",
-    sub_quadratic=True,  # 5/6 of layers are window-1024; each 6th keeps full KV
 )
 
 SMOKE = ModelConfig(
@@ -49,6 +48,5 @@ SMOKE = ModelConfig(
     tie_embeddings=True,
     embed_scale=True,
     activation="gelu",
-    sub_quadratic=True,
     remat="none",
 )

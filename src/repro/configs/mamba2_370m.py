@@ -19,7 +19,6 @@ CONFIG = ModelConfig(
     n_groups=48,
     ssm=SSMConfig(d_inner=2048, head_dim=64, d_state=128, n_groups=1, conv_width=4, chunk=64),
     tie_embeddings=True,
-    sub_quadratic=True,  # O(1) SSM state
     use_scan_kernels=True,  # train the SSD scan through the Pallas kernel pair
 )
 
@@ -36,6 +35,5 @@ SMOKE = ModelConfig(
     n_groups=2,
     ssm=SSMConfig(d_inner=256, head_dim=32, d_state=16, n_groups=1, conv_width=4, chunk=8),
     tie_embeddings=True,
-    sub_quadratic=True,
     remat="none",
 )

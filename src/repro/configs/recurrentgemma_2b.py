@@ -26,7 +26,6 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     embed_scale=True,
     activation="gelu",
-    sub_quadratic=True,  # O(1) recurrent state + bounded window
 )
 
 SMOKE = ModelConfig(
@@ -46,6 +45,5 @@ SMOKE = ModelConfig(
     tie_embeddings=True,
     embed_scale=True,
     activation="gelu",
-    sub_quadratic=True,
     remat="none",
 )

@@ -2,9 +2,9 @@
 
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Tuple
 
-from .base import ModelConfig, SHAPE_CELLS, ShapeCell, get_shape_cell
+from .base import ModelConfig
 from . import (
     deepseek_moe_16b,
     deepseek_v2_236b,
@@ -49,18 +49,3 @@ def get_smoke_config(arch: str) -> ModelConfig:
     except KeyError:
         raise KeyError(f"unknown arch {arch!r}; available: {list(ARCHS)}") from None
 
-
-def cell_applicable(cfg: ModelConfig, cell: ShapeCell) -> Tuple[bool, str]:
-    """Whether a (arch x shape) cell runs, and the reason when it doesn't.
-
-    long_500k requires sub-quadratic attention (assignment rule): full-
-    attention archs skip it, with the skip recorded in DESIGN.md / the
-    dry-run report."""
-    if cell.name == "long_500k" and not cfg.sub_quadratic:
-        return False, "long_500k skipped: full/quadratic attention at 524k context"
-    return True, ""
-
-
-def all_cells() -> List[Tuple[str, str]]:
-    """Full 40-cell assignment (including skips)."""
-    return [(arch, cell.name) for arch in ARCHS for cell in SHAPE_CELLS]

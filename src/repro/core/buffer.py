@@ -106,7 +106,7 @@ class NumpyEventBuffer:
 
     Slower per event on CPython than :class:`ListEventBuffer` (four element
     stores vs one ``list.append``) but allocation-free in steady state; kept
-    for the measured comparison in EXPERIMENTS.md §Perf.
+    for the measured comparison in ``benchmarks/event_throughput.py``.
     """
 
     strategy = "numpy"

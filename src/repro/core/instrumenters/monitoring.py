@@ -6,7 +6,7 @@ callbacks are registered per event kind, receive the code object directly
 (no frame materialization on the fast path), and can be disabled per
 location.  This instrumenter is the modern re-implementation of the paper's
 ``profile`` instrumenter; ``benchmarks/overhead_case2.py`` quantifies the β
-improvement (EXPERIMENTS.md §Perf).
+improvement.
 
 Events observed: PY_START/PY_RETURN (+ PY_UNWIND for exceptional exits and
 PY_YIELD/PY_RESUME so generator suspension balances like ``sys.setprofile``'s

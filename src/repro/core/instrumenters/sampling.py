@@ -9,8 +9,8 @@ active frames were sampled so their matching *return* is recorded too (a
 sampled enter without its exit would corrupt profiles).  Unsampled calls pay
 only a closure-local countdown decrement + a list push — no dict lookup, no
 modulo, no clock read, no region lookup, no buffer append — so β drops
-roughly by the sampling ratio for call-dominated workloads (measured in
-EXPERIMENTS.md §Perf).  ``c_call``-family events carry no frame identity to
+roughly by the sampling ratio for call-dominated workloads (measured by
+``benchmarks/overhead_case2.py``).  ``c_call``-family events carry no frame identity to
 balance against and are dispatched out after the two event-name compares —
 they never touch the counter or the stack.
 

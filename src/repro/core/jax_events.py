@@ -105,7 +105,7 @@ class JaxBridge:
 
 
 # ----------------------------------------------------------------------------
-# AOT (compiled) artifact accounting — also reused by the roofline harness.
+# AOT (compiled) artifact accounting.
 # ----------------------------------------------------------------------------
 
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all", "collective-permute")
@@ -144,7 +144,7 @@ def collective_stats(hlo_text: str) -> Dict[str, Dict[str, float]]:
     Bytes are *wire-estimate* bytes: result-shape bytes scaled by the ring
     factor for the op and its replica-group size g —
     all-reduce 2(g-1)/g, all-gather/reduce-scatter (g-1)/g, all-to-all
-    (g-1)/g, collective-permute 1.  Conventions documented in DESIGN.md §7.
+    (g-1)/g, collective-permute 1.
 
     Async forms are handled: ``*-start`` ops count (their result tuple's
     largest element is the transferred buffer — for all-gather-start the

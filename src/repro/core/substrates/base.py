@@ -5,7 +5,7 @@ plugins for online interpretation).  Substrates here receive *batched*
 event flushes as numpy columns — per-event work in the instrumentation fast
 path is limited to one buffer append; everything expensive happens at flush
 granularity.  (Score-P builds profiles online per event; our deferred design
-is a deliberate, measured overhead optimization — EXPERIMENTS.md §Perf.)
+is a deliberate overhead optimization.)
 """
 
 from __future__ import annotations

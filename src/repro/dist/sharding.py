@@ -85,13 +85,6 @@ def batch_spec(mesh, shape: Tuple[int, ...]) -> P:
     return P()
 
 
-def cache_spec(mesh, shape: Tuple[int, ...]) -> P:
-    """Decode-cache leaves: batch dim over data, everything else replicated
-    (KV heads rarely divide the model axis; sequence stays whole for the
-    ring-buffer window update)."""
-    return batch_spec(mesh, shape)
-
-
 def act_axes(mesh) -> Optional[Tuple[Any, Any]]:
     """(batch_axes, seq_axes) for residual-stream constraints (Megatron-SP).
 
@@ -129,22 +122,3 @@ def opt_state_shardings(mesh, opt_state):
 def batch_shardings(mesh, batch):
     """NamedSharding tree for a host batch (leading dim = global batch)."""
     return jax.tree.map(lambda l: _leaf_sharding(mesh, l, batch_spec), batch)
-
-
-def cache_shardings(mesh, cache):
-    """NamedSharding tree for a decode cache."""
-    return jax.tree.map(lambda l: _leaf_sharding(mesh, l, cache_spec), cache)
-
-
-def with_shardings(shapes, shardings):
-    """Attach shardings to a ShapeDtypeStruct tree (AOT ``.lower`` inputs)."""
-    return jax.tree.map(
-        lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s), shapes, shardings
-    )
-
-
-def describe(mesh, tree) -> str:
-    """One-line sharding census (debug aid): sharded/total leaf counts."""
-    leaves = jax.tree.leaves(params_shardings(mesh, tree))
-    sharded = sum(1 for s in leaves if tuple(s.spec))
-    return f"{sharded}/{len(leaves)} leaves sharded on {dict(mesh.shape)}"

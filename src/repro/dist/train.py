@@ -6,15 +6,11 @@ input shardings + the activation constraints installed by
 ``with_act_sharding``, so the same step runs unchanged on one device or a
 pod mesh (the numerical-equivalence test in tests/test_dist_features.py
 holds it to that).
-
-``jit_train_step`` is the AOT entry used by the dry-run / roofline
-harnesses: it returns a jitted step plus sharding-annotated
-ShapeDtypeStructs for ``.lower()`` — no parameter allocation.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +65,7 @@ def make_train_step(cfg, opt_cfg: adamw.AdamWConfig) -> Callable:
 
 
 # ----------------------------------------------------------------------------
-# Abstract inputs (dry-run: ShapeDtypeStructs only, no allocation)
+# Abstract inputs (ShapeDtypeStructs only, no allocation)
 # ----------------------------------------------------------------------------
 
 def batch_shapes(cfg, global_batch: int, seq_len: int) -> Dict[str, jax.ShapeDtypeStruct]:
@@ -97,21 +93,3 @@ def abstract_state(cfg) -> Tuple[Any, Any]:
     opt = jax.eval_shape(adamw.init, params)
     return params, opt
 
-
-def jit_train_step(cfg, mesh, opt_cfg: Optional[adamw.AdamWConfig] = None):
-    """AOT compile helper: returns ``compile_for(batch_abstract) -> (jitted,
-    (params_s, opt_s, batch_s))`` where the ``*_s`` trees are
-    sharding-annotated ShapeDtypeStructs ready for ``jitted.lower``."""
-    opt_cfg = opt_cfg or adamw.AdamWConfig()
-    cfg = with_act_sharding(cfg, mesh)
-    step = make_train_step(cfg, opt_cfg)
-
-    def compile_for(batch_abstract):
-        params_shapes, opt_shapes = abstract_state(cfg)
-        params_s = shd.with_shardings(params_shapes, shd.params_shardings(mesh, params_shapes))
-        opt_s = shd.with_shardings(opt_shapes, shd.opt_state_shardings(mesh, opt_shapes))
-        batch_s = shd.with_shardings(batch_abstract, shd.batch_shardings(mesh, batch_abstract))
-        jitted = jax.jit(step, donate_argnums=(0, 1))
-        return jitted, (params_s, opt_s, batch_s)
-
-    return compile_for

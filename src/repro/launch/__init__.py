@@ -1,1 +1,1 @@
-"""Launchers: mesh construction, dry-run, train/serve drivers, trace CLI."""
+"""Launchers: mesh construction, train/serve drivers, compile cache, trace CLI."""

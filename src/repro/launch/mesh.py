@@ -1,22 +1,11 @@
 """Mesh construction.  Functions, not module constants — importing this
-module never touches jax device state (required by the dry-run contract)."""
+module never touches jax device state."""
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
-import numpy as np
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """The production mesh: one pod = 16x16 = 256 chips (v5e), two pods for
-    the multi-pod dry-run.  'pod' composes with 'data' for gradient
-    reduction (pure DP across pods: inter-pod links are the slowest, so only
-    per-step gradient all-reduce crosses them)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_elastic_mesh(n_devices: Optional[int] = None, model_parallel: int = 16):
@@ -35,18 +24,6 @@ def make_elastic_mesh(n_devices: Optional[int] = None, model_parallel: int = 16)
         axis_types=(jax.sharding.AxisType.Auto,) * 2,
         devices=devices[: data * model],
     )
-
-
-def make_pipeline_mesh(n_stages: int, n_data: int):
-    """Mesh with an explicit 'stage' axis for GPipe pipeline parallelism."""
-    return jax.make_mesh(
-        (n_data, n_stages), ("data", "stage"),
-        axis_types=(jax.sharding.AxisType.Auto,) * 2,
-    )
-
-
-def describe(mesh) -> str:
-    return f"mesh{tuple(mesh.shape.values())} axes={mesh.axis_names} devices={mesh.devices.size}"
 
 
 def elastic_setup(cfg, topology, use_mesh: bool):

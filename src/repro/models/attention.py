@@ -141,12 +141,7 @@ def _sdpa_q_chunked(
     live score tensor to (B,K,G,chunk,T) — the XLA-level flash-attention
     adaptation used when the Pallas kernel path is off.  The scan body is
     ``jax.checkpoint``-ed so backward recomputes one block's scores at a
-    time instead of saving them all.
-
-    Note for cost accounting: XLA's cost model counts a scan body ONCE, so
-    this path undercounts attention FLOPs by ~nq; the roofline harness
-    therefore lowers with ``attn_chunk_q=0`` (identical math, fully costed)
-    while dry-run memory proofs use this path (see benchmarks/roofline.py)."""
+    time instead of saving them all."""
     b, s, kh, g, h = q.shape
     t = k.shape[1]
     assert s % chunk == 0, (s, chunk)
@@ -211,9 +206,7 @@ def gqa_apply(
             v = _constrain(v, (b_ax, None, None, None))
         scale = _softmax_scale(scale, head_dim)
         with scope("attn_core"):
-            # Mosaic kernels cannot be partitioned automatically: a step
-            # sharded over a mesh keeps the XLA path.
-            if use_flash_kernel and act_pspec is None:
+            if use_flash_kernel:
                 from repro.kernels import ops as _kops
 
                 out = _kops.flash_attention(q, k, v, causal=causal, window=window, scale=scale)

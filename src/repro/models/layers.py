@@ -139,9 +139,7 @@ def chunked_cross_entropy(
     ``lax.scan`` over sequence chunks with a ``jax.checkpoint``-ed body:
     forward and backward both hold one chunk's logits at a time, so peak
     logit memory drops ~n_chunks x.  The baseline path (n_chunks <= 1)
-    materializes (B, S, V) logits directly.  (Roofline lowering uses the
-    baseline path so XLA's cost model sees every flop — scan bodies are
-    costed once; see benchmarks/roofline.py.)
+    materializes (B, S, V) logits directly.
     """
     b, s, d = x.shape
     assert s % n_chunks == 0, (s, n_chunks)

@@ -178,15 +178,14 @@ def lm_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]) -> Tu
 def _block_cache_init(cfg: ModelConfig, spec, batch: int, max_len: int) -> Params:
     mixer, _ = spec
     hd = cfg.resolved_head_dim
-    kv_dtype = jnp.dtype(cfg.kv_cache_dtype)
     if mixer == "attn":
-        return attn.gqa_cache_init(batch, max_len, cfg.n_kv_heads, hd, kv_dtype)
+        return attn.gqa_cache_init(batch, max_len, cfg.n_kv_heads, hd, jnp.bfloat16)
     if mixer == "attn_local":
         w = min(cfg.window or max_len, max_len)
-        return attn.gqa_cache_init(batch, w, cfg.n_kv_heads, hd, kv_dtype)
+        return attn.gqa_cache_init(batch, w, cfg.n_kv_heads, hd, jnp.bfloat16)
     if mixer == "mla":
         m = cfg.mla
-        return attn.mla_cache_init(batch, max_len, m.kv_lora_rank, m.qk_rope_head_dim, kv_dtype)
+        return attn.mla_cache_init(batch, max_len, m.kv_lora_rank, m.qk_rope_head_dim, jnp.bfloat16)
     if mixer == "rglru":
         return rglru_mod.rglru_state_init(batch, cfg.rnn.d_rnn, cfg.rnn.conv_width)
     if mixer == "ssd":
@@ -210,19 +209,18 @@ def _block_prefill(cfg, spec, p, x, max_len, enc):
     if enc is not None and "cross" in p:
         enc_kv = attn.cross_kv(p["cross"], enc, cfg.n_kv_heads, hd)
     h = norm_apply(cfg, p["norm1"], x)
-    kv_dtype = jnp.dtype(cfg.kv_cache_dtype)
     if mixer in ("attn", "attn_local"):
         window = cfg.window if mixer == "attn_local" else None
         cache = attn.gqa_prefill_cache(
             p["mixer"], h, max_len, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=hd, rope_theta=layer_theta(cfg, mixer), window=window,
-            cache_dtype=kv_dtype)
+            cache_dtype=jnp.bfloat16)
     elif mixer == "mla":
         m = cfg.mla
         cache = attn.mla_prefill_cache(
             p["mixer"], h, max_len, n_heads=cfg.n_heads,
             qk_nope_head_dim=m.qk_nope_head_dim, qk_rope_head_dim=m.qk_rope_head_dim,
-            v_head_dim=m.v_head_dim, rope_theta=cfg.rope_theta, cache_dtype=kv_dtype)
+            v_head_dim=m.v_head_dim, rope_theta=cfg.rope_theta, cache_dtype=jnp.bfloat16)
     elif mixer == "rglru":
         cache = rglru_mod.rglru_prefill_state(p["mixer"], h)
     elif mixer == "ssd":
@@ -313,14 +311,7 @@ def prefill(
                 caches[f"p{j}"] = c
             return x, caches
 
-        if cfg.scan_layers:
-            x, cache["groups"] = jax.lax.scan(body, x, stack["groups"])
-        else:
-            per_group = []
-            for g in range(cfg.n_groups):
-                x, c = body(x, jax.tree.map(lambda t: t[g], stack["groups"]))
-                per_group.append(c)
-            cache["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
+        x, cache["groups"] = jax.lax.scan(body, x, stack["groups"])
 
     for i, spec in enumerate(cfg.tail_pattern):
         x, _, c = _block_prefill(cfg, spec, stack["tail"][i], x, max_len, enc)
@@ -334,7 +325,7 @@ def prefill(
 
 def cache_init(cfg: ModelConfig, params: Params, batch: int, max_len: int,
                frames: Optional[jax.Array] = None) -> Params:
-    """Empty cache (decode-from-scratch; serve_step dry-runs use this)."""
+    """Empty cache, for decoding from scratch."""
     enc = _encoder_out(cfg, params, frames) if frames is not None else None
     stack = params["stack"]
     cache: Params = {"head": [], "groups": {}, "tail": []}
@@ -386,17 +377,7 @@ def decode_step(
                 caches[f"p{j}"] = c
             return x, caches
 
-        if cfg.scan_layers:
-            x, new_cache["groups"] = jax.lax.scan(body, x, (stack["groups"], cache["groups"]))
-        else:
-            per_group = []
-            for g in range(cfg.n_groups):
-                x, c = body(
-                    x,
-                    jax.tree.map(lambda t: t[g], (stack["groups"], cache["groups"])),
-                )
-                per_group.append(c)
-            new_cache["groups"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
+        x, new_cache["groups"] = jax.lax.scan(body, x, (stack["groups"], cache["groups"]))
 
     for i, spec in enumerate(cfg.tail_pattern):
         x, c = _block_decode(cfg, spec, stack["tail"][i], cache["tail"][i], x, index)

@@ -5,8 +5,8 @@ TPU adaptation: routing materializes dispatch/combine one-hots of shape
 (groups, S, E, C) and the expert GEMMs run as einsums with the expert axis
 first — the canonical pjit-friendly formulation (the expert axis shards on
 the `model` mesh axis = expert parallelism; XLA inserts the all-to-alls).
-Capacity C = S * top_k / E * capacity_factor, overflow tokens are dropped
-(recorded in DESIGN.md).  Token groups bound the dispatch tensor size:
+Capacity C = S * top_k / E * capacity_factor, overflow tokens are
+dropped.  Token groups bound the dispatch tensor size:
 S*E*C grows ~ S^2 * top_k * cf, so callers group long sequences.
 """
 

@@ -13,7 +13,6 @@ Design notes
 
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -27,16 +26,11 @@ from . import moe as moe_mod
 from . import rglru as rglru_mod
 from . import ssd as ssd_mod
 from .layers import (
-    chunked_cross_entropy,
     dense_init,
-    embed_init,
-    embed_lookup,
     layer_norm,
-    lm_logits,
     mlp_apply,
     mlp_init,
     rms_norm,
-    softmax_cross_entropy,
 )
 
 Params = Dict[str, Any]
@@ -181,8 +175,9 @@ def block_apply(
     mixer, _ = spec
     hd = cfg.resolved_head_dim
     # Mosaic kernels cannot be partitioned automatically: a step sharded
-    # over a mesh keeps the XLA scans.
-    scan_kernels = cfg.use_scan_kernels and cfg.act_pspec is None
+    # over a mesh keeps the XLA paths.
+    kernels = cfg.act_pspec is None
+    scan_kernels = cfg.use_scan_kernels and kernels
     h = norm_apply(cfg, p["norm1"], x)
     if mixer in ("attn", "attn_local", "attn_bidir"):
         window = cfg.window if mixer == "attn_local" else None
@@ -190,7 +185,7 @@ def block_apply(
             p["mixer"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=hd,
             rope_theta=layer_theta(cfg, mixer), causal=(mixer != "attn_bidir"),
             window=window, positions=positions, chunk_q=cfg.attn_chunk_q,
-            use_flash_kernel=cfg.use_flash_kernel, act_pspec=cfg.act_pspec,
+            use_flash_kernel=cfg.use_flash_kernel and kernels, act_pspec=cfg.act_pspec,
             scale=cfg.attn_scale)
     elif mixer == "mla":
         m = cfg.mla
@@ -310,11 +305,7 @@ def stack_apply(
 
         body = group_body if per_block else _maybe_remat(cfg, group_body)
         with scope("layer_stack"):
-            if cfg.scan_layers:
-                (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
-            else:  # unrolled: every layer visible to the XLA cost model
-                for g in range(cfg.n_groups):
-                    (x, aux), _ = body((x, aux), jax.tree.map(lambda t: t[g], params["groups"]))
+            (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
 
     for i, spec in enumerate(cfg.tail_pattern):
         x, a = block_apply(cfg, spec, params["tail"][i], x,

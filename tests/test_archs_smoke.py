@@ -36,7 +36,7 @@ def _batch(cfg, key):
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_full_config_exactness(arch):
-    """The FULL config matches the assignment spec (exercised via dry-run only)."""
+    """The FULL config matches its published widths."""
     cfg = get_config(arch)
     spec = {
         "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256000),
@@ -140,7 +140,7 @@ def test_smoke_prefill_decode_consistency(arch):
 @pytest.mark.parametrize("arch", ["gemma3-12b", "recurrentgemma-2b"])
 def test_window_cache_bounded(arch):
     """Local-attention caches must be ring buffers of window size — this is
-    what makes long_500k feasible for the sub-quadratic archs."""
+    what keeps their decode memory bounded at long contexts."""
     cfg = get_smoke_config(arch)
     params = lm_init(jax.random.PRNGKey(0), cfg)
     max_len = 64
@@ -169,7 +169,7 @@ def test_window_cache_bounded(arch):
 
 def test_long_decode_past_window():
     """Decode far past the window: ring buffer + RG-LRU state stay finite and
-    depend on position (sanity for long_500k semantics)."""
+    depend on position."""
     cfg = get_smoke_config("recurrentgemma-2b")
     params = lm_init(jax.random.PRNGKey(0), cfg)
     c = cache_init(cfg, params, 1, cfg.window)  # max_len == window

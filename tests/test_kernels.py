@@ -291,14 +291,16 @@ def test_ssd_kernel_final_state_vs_chunked_ref():
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=2e-4, atol=2e-4)
 
 
-def test_ssd_kernel_only_in_unsharded_steps():
-    """A step sharded over a mesh keeps the XLA scan: Mosaic kernels cannot
-    be partitioned automatically."""
+@pytest.mark.parametrize("arch", ["mamba2-370m", "mistral-nemo-12b"])
+def test_ssd_kernel_only_in_unsharded_steps(arch):
+    """With its kernels on, an unsharded step runs the SSD scan or flash
+    attention as Pallas kernels, and a step sharded over a mesh keeps the
+    XLA paths: Mosaic kernels cannot be partitioned automatically."""
     from repro.configs import get_smoke_config
     from repro.dist.train import with_act_sharding
     from repro.models import lm_init, lm_loss
 
-    cfg = get_smoke_config("mamba2-370m").scaled(use_scan_kernels=True)
+    cfg = get_smoke_config(arch).scaled(use_scan_kernels=True, use_flash_kernel=True)
     params = jax.eval_shape(lambda: lm_init(jax.random.PRNGKey(0), cfg))
     toks = jax.ShapeDtypeStruct((2, 16), jnp.int32)
     batch = {"tokens": toks, "labels": toks}
@@ -313,16 +315,16 @@ def test_ssd_kernel_only_in_unsharded_steps():
 def test_gqa_flash_kernel_matches_xla_path(head_dim):
     """gqa_apply at SMOKE widths: the flash kernel pair gives the query-chunked
     XLA path's output and parameter gradients within bfloat16 tolerance, and
-    a step sharded over a mesh (``act_pspec`` set) keeps the XLA path."""
+    the gradient runs the forward, dK/dV and dQ kernels."""
     from repro.models.attention import gqa_apply, gqa_init
 
     params = gqa_init(jax.random.PRNGKey(0), 128, 4, 2, head_dim)
     x = _rand(jax.random.PRNGKey(1), (2, 256, 128), jnp.bfloat16)
     weight = _rand(jax.random.PRNGKey(2), (2, 256, 128))
 
-    def loss(p, x, flash, act_pspec=None):
+    def loss(p, x, flash):
         out = gqa_apply(p, x, n_heads=4, n_kv_heads=2, head_dim=head_dim, rope_theta=1e6,
-                        chunk_q=64, use_flash_kernel=flash, act_pspec=act_pspec)
+                        chunk_q=64, use_flash_kernel=flash)
         return jnp.sum(out.astype(jnp.float32) * weight), out
 
     def run(flash):
@@ -335,13 +337,8 @@ def test_gqa_flash_kernel_matches_xla_path(head_dim):
         assert np.linalg.norm(u - w) <= 2e-2 * np.linalg.norm(w), name
         np.testing.assert_allclose(u, w, rtol=0, atol=2e-2 * np.max(np.abs(w)), err_msg=name)
 
-    def step_jaxpr(act_pspec):
-        return str(jax.make_jaxpr(jax.grad(lambda p, x: loss(p, x, True, act_pspec)[0]))(params, x))
-
-    assert step_jaxpr(None).count("flash_attention_") == 3  # forward, dK/dV, dQ
-    with jax.make_mesh((1, 1), ("data", "model")):
-        sharded = step_jaxpr(("data", None))
-    assert "flash_attention" not in sharded and "pallas_call" not in sharded
+    step = str(jax.make_jaxpr(jax.grad(lambda p, x: loss(p, x, True)[0]))(params, x))
+    assert step.count("flash_attention_") == 3  # forward, dK/dV, dQ
 
 
 def test_ssd_kernel_exp_within_two_ulp():
