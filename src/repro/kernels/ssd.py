@@ -17,9 +17,21 @@ low-rank inter-chunk state passing:
     h'      = chunk_decay h + (x dt * decay_out)^T @ B   (P, N), likewise
 
 ``C B^T`` depends on the group only, so it is computed once per chunk for
-all heads of the block.  The GPU implementation leans on warp shuffles
-for the cumsum; Mosaic has no cumsum, so here the cumulative sums are
-masked sums over the (L, L) lower triangle the kernel builds anyway.
+all heads of the block.
+
+The decay terms are built once per grid step for the whole block, lane
+dense: dt arrives as the block's (hb, L) tile, one row a head, and log a,
+its cumsum (a product with the triangle), decay_in, decay_out and the
+chunk decay are taken over those rows, one ``_exp`` over a few vregs each.
+Built head by head as (L, 1) columns they cost a whole vreg per 8 values,
+and each column took a masked lane reduction; one transpose of the rows
+gives every head's columns, and a lane gather spreads a lane group's
+columns over its lanes in one step.  The one exp left per head is its
+(L, L) decay tile, and a lane group's k tiles sit side by side in one
+(L, kL) tile, so that y_diag and d scores are single products over it.
+The backward kernel reduces its per-head terms of d log a and d dt over
+each head's lanes by a transpose and sublane sums, into (hb, L) rows, and
+finishes both rows for the block at once.
 
 Under differentiation the forward kernel also writes the states entering
 each chunk, and the backward kernel reads them back while it carries dh
@@ -93,67 +105,83 @@ def _exp(x):
     return p * jax.lax.bitcast_convert_type((n.astype(jnp.int32) + 127) << 23, jnp.float32)
 
 
-def _masks(chunk: int):
-    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    return li >= lj, li == lj
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
 
-# Vectors within a chunk flip between a (1, L) row and an (L, 1) column,
-# and sum along the chunk, through masked sums over the (L, L) square.
-# These are exact, and cost the VPU less than a 6-pass MXU product would.
-def _col(row, eye):
-    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+def _tri(chunk):
+    """(L, L) float 0/1: row i holds 1 at lanes j <= i."""
+    return (_iota((chunk, chunk), 0) >= _iota((chunk, chunk), 1)).astype(jnp.float32)
 
 
-def _row(col, eye):
-    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+def _block_rows(dt, a):
+    """The block's decays within the chunk as (hb, L) rows, one a head, from
+    its dt rows and its decay rates a as an (hb, 1) column: dt, decay_in =
+    exp(acs), decay_out = exp(total - acs) and acs, the inclusive cumsum of
+    log a (a product with the triangle, exact at HIGHEST); total is acs's
+    last lane, so decay_in's last lane is the chunk decay exp(total)."""
+    chunk = dt.shape[1]
+    acs = _mm_nt(dt * a, _tri(chunk))
+    return dt, _exp(acs), _exp(acs[:, chunk - 1:] - acs), acs
 
 
-def _head_terms(dt_row, a, tri, eye):
-    """This head's decays within the chunk, from its dt as a (1, L) row.
-
-    Returns the dt column, the inclusive cumsum of log a as a column, the
-    masked decay matrix exp(acs_i - acs_j), and the chunk's sum of log a
-    as (1, 1)."""
-    log_a = dt_row * a  # (1, L) negative
-    acs_col = jnp.sum(jnp.where(tri, log_a, 0.0), axis=1, keepdims=True)  # sum_{k <= i}
-    decay = jnp.where(tri, _exp(acs_col - _row(acs_col, eye)), 0.0)  # (L, L)
-    total = jnp.sum(log_a, axis=1, keepdims=True)  # (1, 1)
-    return _col(dt_row, eye), acs_col, decay, total
+# Quantities of the block's columns tile, in its order.
+_DT, _IN, _OUT, _ACS = range(4)
 
 
-class _Head(NamedTuple):
-    lanes: jax.Array  # (L, kP) bool: this head's lanes of the group
-    rows: jax.Array  # (kP, 1) bool: this head's rows of the stacked state
-    a: jax.Array  # its decay rate
-    decay_in: jax.Array  # (L, 1) exp(acs)
-    decay_out: jax.Array  # (L, 1) exp(total - acs)
-    chunk_decay: jax.Array  # (1, 1) exp(total)
-    decay: jax.Array  # (L, L) masked exp(acs_i - acs_j)
+def _take(src, idx):
+    """src[r, idx[r, c]]: a lane gather at idx's shape, src padded or idx
+    widened so that the two match, as Mosaic's gather needs."""
+    rows, w = idx.shape
+    src = jnp.broadcast_to(src, (rows, src.shape[1]))
+    if src.shape[1] < w:
+        src = jnp.concatenate([src, jnp.zeros((rows, w - src.shape[1]), src.dtype)], axis=1)
+    elif src.shape[1] > w:
+        idx = jnp.concatenate([idx, jnp.zeros((rows, src.shape[1] - w), idx.dtype)], axis=1)
+    return jnp.take_along_axis(src, idx, axis=1, mode="promise_in_bounds")[:, :w]
 
 
-def _group_terms(dt_ref, a_ref, head0, q, *, chunk, k, p, tri, eye):
-    """The decays of lane group ``q``'s k heads: a ``_Head`` each, and dt,
-    decay_in and decay_out spread over the group's (L, kP) lanes and the
-    chunk decay over its (kP, 1) state rows."""
-    lane_head = jax.lax.broadcasted_iota(jnp.int32, (chunk, k * p), 1) // p
-    row_head = jax.lax.broadcasted_iota(jnp.int32, (k * p, 1), 0) // p
-    heads = []
-    dt_l = in_l = out_l = jnp.zeros((chunk, k * p), jnp.float32)
-    chunk_decay_r = jnp.zeros((k * p, 1), jnp.float32)
-    for j in range(k):
-        r = q * k + j
-        a = a_ref[head0 + r]
-        dt_col, acs_col, decay, total = _head_terms(dt_ref[0, 0, 0, pl.ds(r, 1), :], a, tri, eye)
-        hd = _Head(lane_head == j, row_head == j, a, _exp(acs_col), _exp(total - acs_col),
-                   _exp(total), decay)
-        dt_l = jnp.where(hd.lanes, dt_col, dt_l)
-        in_l = jnp.where(hd.lanes, hd.decay_in, in_l)
-        out_l = jnp.where(hd.lanes, hd.decay_out, out_l)
-        chunk_decay_r = jnp.where(hd.rows, hd.chunk_decay, chunk_decay_r)
-        heads.append(hd)
-    return heads, dt_l, in_l, out_l, chunk_decay_r
+class _Group(NamedTuple):
+    dt: jax.Array  # (L, kP): each head's dt column over its P lanes
+    decay_in: jax.Array  # (L, kP) exp(acs)
+    decay_out: jax.Array  # (L, kP) exp(total - acs)
+    chunk_decay: jax.Array  # (kP, N): exp(total) over each head's state rows
+    decay: jax.Array  # (L, kL): the k heads' masked exp(acs_i - acs_j) side by side
+
+
+def _group_terms(cols, q, *, hb, k, p, n):
+    """Lane group ``q``'s decays, from the block's (L, 4 hb) columns tile
+    (``_block_rows`` transposed): one lane gather spreads each quantity
+    over the group's lanes, and the group's k decay tiles take one exp."""
+    chunk = cols.shape[0]
+    head0 = q * k
+
+    def spread(what, width, unit):
+        return _take(cols, what * hb + head0 + _iota((chunk, width), 1) // unit)
+
+    acs = spread(_ACS, k * chunk, chunk)  # (L, kL): acs_i of head j in its L lanes
+    i, j = _iota(acs.shape, 0), _iota(acs.shape, 1) % chunk
+    acs_j = jnp.sum(jnp.where(i == j, acs, 0.0), axis=0, keepdims=True)  # (1, kL): acs_j, exact
+    # The chunk decay is decay_in's last row; a row of the state takes its head's.
+    last = cols[chunk - 1:]
+    return _Group(spread(_DT, k * p, p), spread(_IN, k * p, p), spread(_OUT, k * p, p),
+                  _take(last, _IN * hb + head0 + _iota((k * p, n), 0) // p),
+                  jnp.where(i >= j, _exp(acs - acs_j), 0.0))
+
+
+def _by_head(v, k, p):
+    """(L, kP) -> (kL, kP): k copies of v stacked, copy j kept to head j's
+    lanes, so a product with the group's (L, kL) decay tile keeps heads apart."""
+    head = _iota(v.shape, 1) // p
+    return jnp.concatenate([jnp.where(head == j, v, 0.0) for j in range(k)], axis=0)
+
+
+def _head_sums(v, k, width):
+    """(L, k width) -> (k, L): row j sums head j's lanes, through one
+    transpose and sublane sums instead of a masked lane reduction a head."""
+    t = v.T
+    return jnp.concatenate([jnp.sum(t[j * width:(j + 1) * width], axis=0, keepdims=True)
+                            for j in range(k)], axis=0)
 
 
 def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, chunk, hb, k, p, save_states):
@@ -167,28 +195,25 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, chunk, hb, k, p, save
     def _init():
         h_scratch[...] = jnp.zeros_like(h_scratch)
 
-    tri, eye = _masks(chunk)
     b = b_ref[0, 0]  # (L, N)
     c = c_ref[0, 0]  # (L, N)
-    cb = _mm_nt(c, b)  # (L, L), shared by the block's heads
-    head0 = pl.program_id(1) * hb
+    n = b.shape[1]
+    # C B^T, shared by the block's heads, once for each head of a lane group: (L, kL)
+    cbk = _mm_nt(c, jnp.concatenate([b] * k, axis=0))
+    cols = jnp.concatenate(_block_rows(dt_ref[0, 0, 0], a_ref[0]), axis=0).T  # (L, 4 hb)
     w = k * p
 
     def group(q, carry):
-        heads, dt_l, in_l, out_l, chunk_decay_r = _group_terms(
-            dt_ref, a_ref, head0, q, chunk=chunk, k=k, p=p, tri=tri, eye=eye)
+        t = _group_terms(cols, q, hb=hb, k=k, p=p, n=n)
         off = pl.multiple_of(q * w, w)
-        xdt = x_ref[0, :, pl.ds(off, w)] * dt_l  # (L, kP)
+        xdt = x_ref[0, :, pl.ds(off, w)] * t.dt  # (L, kP)
         h = h_scratch[q]  # (kP, N): the k heads' states entering this chunk
-        # y_diag = scores @ xdt, the k heads' scores stacked on one product
-        diag = _mm(jnp.concatenate([cb * hd.decay for hd in heads], axis=0), xdt)  # (kL, kP)
-        y = in_l * _mm_nt(c, h)
-        for j, hd in enumerate(heads):
-            y = jnp.where(hd.lanes, y + diag[j * chunk:(j + 1) * chunk], y)
+        # y_diag = scores @ xdt for the k heads in one product over their side-by-side tiles
+        y = t.decay_in * _mm_nt(c, h) + _mm(cbk * t.decay, _by_head(xdt, k, p))
         y_ref[0, :, pl.ds(off, w)] = y.astype(y_ref.dtype)
         if save_states:
             states_ref[0, q, 0] = h
-        h_scratch[q] = chunk_decay_r * h + _mm_tn(xdt * out_l, b)
+        h_scratch[q] = t.chunk_decay * h + _mm_tn(xdt * t.decay_out, b)
         return carry
 
     jax.lax.fori_loop(0, hb // k, group, 0)
@@ -199,69 +224,81 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, *refs, chunk, hb, k, p, save
 
 
 def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, states_ref, dy_ref, dfinal_ref,
-                dx_ref, ddt_ref, dla_ref, db_ref, dc_ref, dh_scratch, *, chunk, hb, k, p):
+                dx_ref, ddt_ref, dla_ref, db_ref, dc_ref, dh_scratch, acs_rows, out_rows,
+                dt_rows, gh_rows, *, chunk, hb, k, p):
     @pl.when(pl.program_id(2) == 0)  # the last chunk comes first
     def _init():
         dh_scratch[...] = dfinal_ref[0]
 
-    tri, eye = _masks(chunk)
     b = b_ref[0, 0]  # (L, N)
     c = c_ref[0, 0]
-    cb = _mm_nt(c, b)
-    head0 = pl.program_id(1) * hb
+    n = b.shape[1]
+    cbk = _mm_nt(c, jnp.concatenate([b] * k, axis=0))  # (L, kL)
+    a = a_ref[0]  # (hb, 1)
+    rows = _block_rows(dt_ref[0, 0, 0], a)
+    cols = jnp.concatenate(rows, axis=0).T
     w = k * p
 
-    def lane_sum(v, mine):  # (L, kP) -> (L, 1) over one head's lanes
-        return jnp.sum(jnp.where(mine, v, 0.0), axis=1, keepdims=True)
-
     def group(q, carry):
-        db, dc, d_cb = carry
-        heads, dt_l, in_l, out_l, chunk_decay_r = _group_terms(
-            dt_ref, a_ref, head0, q, chunk=chunk, k=k, p=p, tri=tri, eye=eye)
+        db, dc, d_cbk = carry
+        t = _group_terms(cols, q, hb=hb, k=k, p=p, n=n)
         off = pl.multiple_of(q * w, w)
         x = x_ref[0, :, pl.ds(off, w)]  # (L, kP)
         dy = dy_ref[0, :, pl.ds(off, w)]
-        xdt = x * dt_l
+        xdt = x * t.dt
         h = states_ref[0, q, 0]  # (kP, N): the states entering this chunk
         g = dh_scratch[q]  # (kP, N): d loss / d the states leaving it
-        y_off = _mm_nt(c, h)  # (L, kP): C h^T per head, before decay_in
         bg = _mm_nt(b, g)  # (L, kP)
-        dxdt = out_l * bg  # from h' = chunk_decay h + xdt^T @ (B * decay_out)
-        dy_off = dy * y_off
-        xbg = xdt * bg
-        gh = jnp.sum(g * h, axis=1, keepdims=True)  # (kP, 1)
-        # y_diag = scores @ xdt; scores = cb * decay
-        d_scores_k = _mm_nt(jnp.concatenate([jnp.where(hd.lanes, dy, 0.0) for hd in heads], axis=0),
-                            xdt)  # (kL, L): the k heads' d scores stacked
-        for j, (mine, rows, a, decay_in, decay_out, chunk_decay, decay) in enumerate(heads):
-            scores = cb * decay
-            d_scores = d_scores_k[j * chunk:(j + 1) * chunk]
-            dxdt = jnp.where(mine, dxdt + _mm_tn(scores, dy), dxdt)
-            d_cb = d_cb + d_scores * decay  # dB, dC from it after the loop, once for all heads
-            m = d_scores * scores  # d acs_i gets its row sum, d acs_j loses its column sum
-            dacs = jnp.sum(m, axis=1, keepdims=True) - _col(jnp.sum(m, axis=0, keepdims=True), eye)
-            dacs = dacs + decay_in * lane_sum(dy_off, mine)  # y_off = decay_in * (C @ h^T)
-            d_out = decay_out * lane_sum(xbg, mine)  # (L, 1)
-            d_total = chunk_decay * jnp.sum(jnp.where(rows, gh, 0.0), axis=0, keepdims=True) + (
-                jnp.sum(d_out, axis=0, keepdims=True))
-            dacs = dacs - d_out
-            # acs = cumsum(log_a) and total = sum(log_a); log_a = dt * a; xdt = x * dt
-            dla = jnp.sum(jnp.where(tri, dacs, 0.0), axis=0, keepdims=True) + d_total  # (1, L)
-            ddt = _row(lane_sum(dxdt * x, mine), eye) + a * dla
-            r = q * k + j
-            ddt_ref[0, 0, 0, pl.ds(r, 1), :] = ddt
-            dla_ref[0, 0, 0, pl.ds(r, 1), :] = dla
-        dy_in = dy * in_l
+        # y_diag = scores @ xdt; scores = cb * decay, the k heads' tiles side by side
+        scores = cbk * t.decay  # (L, kL)
+        d_scores = _mm_nt(dy, _by_head(xdt, k, p))  # (L, kL)
+        # from h' = chunk_decay h + xdt^T @ (B * decay_out), and y_diag: each
+        # head's scores^T @ dy, its tile stacked over dy kept to its lanes
+        stacked = jnp.concatenate([scores[:, j * chunk:(j + 1) * chunk] for j in range(k)], axis=0)
+        dxdt = t.decay_out * bg + _mm_tn(stacked, _by_head(dy, k, p))
+        d_cbk = d_cbk + d_scores * t.decay  # dB, dC from it after the loop, once for all heads
+        # d acs_i gets each row sum of m and loses each column sum; y_off =
+        # decay_in * (C @ h^T) adds d_in.  Through decay_out, h' takes
+        # d_out from d acs, which the chunk total gives back (after the loop).
+        m = d_scores * scores
+        d_in = dy * t.decay_in * _mm_nt(c, h)
+        if p == chunk:  # the same lanes a head: one transpose for both
+            d_acs = _head_sums(m + d_in, k, chunk)
+        else:
+            d_acs = _head_sums(m, k, chunk) + _head_sums(d_in, k, p)
+        col_sums = jnp.sum(m, axis=0, keepdims=True)  # (1, kL)
+        d_acs = d_acs - jnp.concatenate(
+            [col_sums[:, j * chunk:(j + 1) * chunk] for j in range(k)], axis=0)
+        heads = pl.ds(q * k, k)
+        acs_rows[heads, :] = d_acs  # (k, L)
+        out_rows[heads, :] = _head_sums(xdt * bg * t.decay_out, k, p)  # d_out
+        dt_rows[heads, :] = _head_sums(dxdt * x, k, p)  # xdt = x * dt
+        gh = g * h
+        gh_rows[heads, :] = jnp.concatenate(
+            [jnp.sum(gh[j * p:(j + 1) * p], axis=0, keepdims=True) for j in range(k)], axis=0)
+        dy_in = dy * t.decay_in
         dc = dc + _mm(dy_in, h)
-        db = db + _mm(xdt * out_l, g)
-        dx_ref[0, :, pl.ds(off, w)] = (dxdt * dt_l).astype(dx_ref.dtype)
-        dh_scratch[q] = _mm_tn(dy_in, c) + chunk_decay_r * g
-        return db, dc, d_cb
+        db = db + _mm(xdt * t.decay_out, g)
+        dx_ref[0, :, pl.ds(off, w)] = (dxdt * t.dt).astype(dx_ref.dtype)
+        dh_scratch[q] = _mm_tn(dy_in, c) + t.chunk_decay * g
+        return db, dc, d_cbk
 
     zeros = jnp.zeros(b.shape, jnp.float32)
-    db, dc, d_cb = jax.lax.fori_loop(0, hb // k, group, (zeros, zeros, jnp.zeros_like(cb)))
+    db, dc, d_cbk = jax.lax.fori_loop(0, hb // k, group, (zeros, zeros, jnp.zeros_like(cbk)))
+    d_cb = sum(d_cbk[:, j * chunk:(j + 1) * chunk] for j in range(k))  # (L, L)
     db_ref[0, 0] = db + _mm_tn(d_cb, c)
     dc_ref[0, 0] = dc + _mm(d_cb, b)
+    # The block's d log a as (hb, L) rows: acs = cumsum(log_a) takes the
+    # reverse cumsum of d acs.  total = sum(log_a) takes chunk_decay * (dh' . h)
+    # and the sum of d_out, whose lanes from l on cancel d_out in d acs's
+    # reverse cumsum at l: what is left at l is d_out's sum over lanes < l.
+    tri = _tri(chunk)
+    d_total = rows[_IN][:, chunk - 1:] * jnp.sum(gh_rows[...], axis=1, keepdims=True)
+    dla = _mm(jnp.concatenate([acs_rows[...], out_rows[...]], axis=1),
+              jnp.concatenate([tri, 1.0 - tri], axis=0)) + d_total
+    # log_a = dt * a and xdt = x * dt
+    ddt_ref[0, 0, 0] = dt_rows[...] + a * dla
+    dla_ref[0, 0, 0] = dla
 
 
 def _dims(xl, bt, h, chunk):
@@ -291,7 +328,7 @@ def _specs(dims, chunk, *, reverse: bool):
         part=pl.BlockSpec((1, 1, chunk, n), lambda ib, j, ic: (ib, j, cix(ic), 0)),
         state=pl.BlockSpec((1, hb // k, 1, k * p, n), lambda ib, j, ic: (ib, j, cix(ic), 0, 0)),
         final=pl.BlockSpec((1, hb // k, k * p, n), lambda ib, j, ic: (ib, j, 0, 0)),
-        smem=pl.BlockSpec(memory_space=pltpu.SMEM),
+        head=pl.BlockSpec((1, hb, 1), lambda ib, j, ic: (j, 0, 0)),
     )
 
 
@@ -315,13 +352,13 @@ def _fwd_call(xl, dtt, a, bt, ct, *, chunk: int, save_states: bool, interpret: b
     return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, hb=hb, k=k, p=p, save_states=save_states),
         grid=_grid(dims),
-        in_specs=[sp["seq"], sp["row"], sp["smem"], sp["group"], sp["group"]],
+        in_specs=[sp["seq"], sp["row"], sp["head"], sp["group"], sp["group"]],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hb // k, k * p, n), jnp.float32)],
         compiler_params=_PARAMS,
         interpret=interpret,
-    )(xl, dtt, a, bt, ct)
+    )(xl, dtt, a.reshape(h // hb, hb, 1), bt, ct)
 
 
 def _bwd_call(xl, dtt, a, bt, ct, states, dyl, dfinal, *, chunk: int, interpret: bool):
@@ -331,7 +368,7 @@ def _bwd_call(xl, dtt, a, bt, ct, states, dyl, dfinal, *, chunk: int, interpret:
     return pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, hb=hb, k=k, p=p),
         grid=_grid(dims),
-        in_specs=[sp["seq"], sp["row"], sp["smem"], sp["group"], sp["group"], sp["state"],
+        in_specs=[sp["seq"], sp["row"], sp["head"], sp["group"], sp["group"], sp["state"],
                   sp["seq"], sp["final"]],
         out_specs=[sp["seq"], sp["row"], sp["row"], sp["part"], sp["part"]],
         out_shape=[jax.ShapeDtypeStruct(xl.shape, xl.dtype),
@@ -339,10 +376,13 @@ def _bwd_call(xl, dtt, a, bt, ct, states, dyl, dfinal, *, chunk: int, interpret:
                    jax.ShapeDtypeStruct(dtt.shape, jnp.float32),
                    jax.ShapeDtypeStruct((bsz, h // hb, s, n), jnp.float32),
                    jax.ShapeDtypeStruct((bsz, h // hb, s, n), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((hb // k, k * p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb // k, k * p, n), jnp.float32),
+                        *(pltpu.VMEM((hb, chunk), jnp.float32) for _ in range(3)),
+                        pltpu.VMEM((hb, n), jnp.float32)],
         compiler_params=_PARAMS,
         interpret=interpret,
-    )(xl, dtt, a, bt, ct, states, dyl, dfinal.reshape(bsz, h // k, k * p, n))
+    )(xl, dtt, a.reshape(h // hb, hb, 1), bt, ct, states, dyl,
+      dfinal.reshape(bsz, h // k, k * p, n))
 
 
 def ssd_chunk_scan_fwd(

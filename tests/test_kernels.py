@@ -194,6 +194,9 @@ def test_rg_lru_property_bounded(s, n, decay):
         (1, 64, 4, 32, 2, 16, 16),  # grouped B/C
         (1, 256, 2, 64, 1, 128, 64),  # larger state
         (2, 128, 4, 64, 1, 128, 64),  # mamba2-370m head layout, chip tiling
+        (1, 128, 32, 64, 1, 128, 64),  # mamba2-370m's block: 32 heads, two a lane group
+        (1, 128, 2, 128, 1, 32, 64),  # one head a lane group (P 128)
+        (1, 128, 64, 64, 1, 32, 64),  # granite: a group's 64 heads in two blocks of 32
     ],
 )
 def test_ssd_kernel_vs_sequential_ref(b, s, h, p, g, n, chunk):
@@ -249,25 +252,32 @@ def _ssd_inputs(seed, b, s, h, p, g, n):
 
 
 @pytest.mark.parametrize(
-    "h,g,chunk",
+    "h,g,chunk,p,n",
     [
-        (2, 1, 16),
-        (2, 1, 32),
-        (4, 2, 16),  # grouped B/C: dB, dC summed over each group's heads
-        (4, 2, 32),
-        (64, 1, 16),  # more heads per group than one kernel block takes
+        pytest.param(2, 1, 16, 32, 16, id="2-1-16"),
+        pytest.param(2, 1, 32, 32, 16, id="2-1-32"),
+        # grouped B/C: dB, dC summed over each group's heads
+        pytest.param(4, 2, 16, 32, 16, id="4-2-16"),
+        pytest.param(4, 2, 32, 32, 16, id="4-2-32"),
+        # more heads per group than one kernel block takes
+        pytest.param(64, 1, 16, 8, 8, id="64-1-16"),
+        # mamba2-370m's block at chunk 64: 32 heads of 64 lanes, two a lane group
+        pytest.param(32, 1, 64, 64, 128, id="32-1-64-p64-n128"),
+        pytest.param(2, 1, 64, 128, 16, id="2-1-64-p128"),  # one head a lane group
+        # granite's 64 heads in two blocks of 32 at chunk 64
+        pytest.param(64, 1, 64, 64, 16, id="64-1-64-p64"),
     ],
 )
-def test_ssd_kernel_vjp_vs_refs(h, g, chunk):
+def test_ssd_kernel_vjp_vs_refs(h, g, chunk, p, n):
     """The backward kernel's (dx, d dt, d a, dB, dC) against autodiff of the
     chunked XLA scan and of the token-by-token recurrence, with cotangents
     on both the output and the final state."""
     from repro.models.ssd import ssd_chunked_ref
 
-    p, n = (8, 8) if h > 8 else (32, 16)
-    args = _ssd_inputs(7, 2, 64, h, p, g, n)
+    s = max(64, 2 * chunk)
+    args = _ssd_inputs(7, 2, s, h, p, g, n)
     k1, k2 = jax.random.split(jax.random.PRNGKey(8))
-    dy = _rand(k1, (2, 64, h, p))
+    dy = _rand(k1, (2, s, h, p))
     dfinal = _rand(k2, (2, h, p, n), scale=0.1)
     _, vjp = jax.vjp(lambda *z: ops.ssd_chunk_scan(*z, chunk=chunk), *args)
     got = vjp((dy, dfinal))
@@ -278,6 +288,47 @@ def test_ssd_kernel_vjp_vs_refs(h, g, chunk):
                 assert u.shape == v.shape, name
                 np.testing.assert_allclose(np.asarray(u), np.asarray(v), rtol=2e-4, atol=2e-4,
                                            err_msg=name)
+
+
+def test_ssd_kernel_block_decay_rows_match_per_head_formulas():
+    """The kernels' lane-dense decay terms against each head's formulas in
+    float64: the block's (hb, L) rows, and a lane group's spreads over its
+    lanes, state rows and side-by-side (L, L) tiles."""
+    from repro.kernels.ssd import _DT, _IN, _OUT, _block_rows, _group_terms
+
+    hb, chunk, k, p, n = 32, 64, 2, 64, 128
+    k1, k2 = jax.random.split(jax.random.PRNGKey(10))
+    dt = jax.random.uniform(k1, (hb, chunk), minval=0.001, maxval=0.2)
+    a = -jnp.exp(jax.random.uniform(k2, (hb, 1), minval=-2.0, maxval=2.0))
+    rows = jax.jit(_block_rows)(dt, a)
+    # acs sums L terms of one sign: within L float32 roundings of float64
+    acs = np.asarray(rows[-1], np.float64)
+    np.testing.assert_allclose(
+        acs, np.cumsum(np.asarray(dt, np.float64) * np.asarray(a, np.float64), axis=1),
+        rtol=chunk * 2.0 ** -24, atol=0)
+    np.testing.assert_array_equal(np.asarray(rows[_DT]), np.asarray(dt))
+    # the exps, of differences taken in float32 as the kernels take them
+    acs32 = np.asarray(rows[-1])
+    for what, value in ((_IN, np.exp(acs)), (_OUT, np.exp(acs32[:, -1:] - acs32, dtype=np.float64))):
+        np.testing.assert_allclose(np.asarray(rows[what]), value, rtol=4e-7, atol=0)
+
+    cols = jnp.concatenate(rows, axis=0).T
+    kernel_rows = [np.asarray(r, np.float64) for r in rows]
+    tri = np.tril(np.ones((chunk, chunk), bool))
+    for q in (0, 5, hb // k - 1):
+        t = jax.jit(functools.partial(_group_terms, hb=hb, k=k, p=p, n=n))(cols, q)
+        for j in range(k):
+            head, lanes = q * k + j, slice(j * p, (j + 1) * p)
+            for got, what in ((t.dt, _DT), (t.decay_in, _IN), (t.decay_out, _OUT)):
+                np.testing.assert_array_equal(np.asarray(got)[:, lanes],
+                                              np.repeat(kernel_rows[what][head][:, None], p, 1))
+            np.testing.assert_array_equal(np.asarray(t.chunk_decay)[lanes],
+                                          np.full((p, n), kernel_rows[_IN][head, -1]))
+            r = acs32[head]
+            decay = np.where(tri, np.exp(np.minimum(r[:, None] - r[None, :], 0.0), dtype=np.float64),
+                             0.0)
+            np.testing.assert_allclose(np.asarray(t.decay)[:, j * chunk:(j + 1) * chunk], decay,
+                                       rtol=4e-7, atol=0)
 
 
 def test_ssd_kernel_final_state_vs_chunked_ref():
